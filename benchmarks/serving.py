@@ -25,7 +25,7 @@ def build(args, *, speculation: bool = False):
     from repro.serving.demo import build_demo_zoo
     from repro.serving.engine import BlockEngine, EngineConfig
 
-    cfg, _, zoo = build_demo_zoo(seed=0)
+    cfg, zoo = build_demo_zoo(seed=0)
     max_len = args.prompt_len + args.gen_len
     engine = BlockEngine(zoo, max_len=max_len, config=EngineConfig(
         max_active=args.requests,
@@ -185,7 +185,12 @@ def _measure(args) -> dict:
     spec = {}
     if getattr(args, "speculation", True):
         spec = _measure_spec(args, b_tps, b_results)
+    import jax
+
+    dev = jax.devices()[0]
     return {
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
         **spec,
         **latency_percentiles(b_results),
         **request_time_percentiles(b_results),
@@ -268,6 +273,9 @@ def main():
     ap.add_argument("--spec-metrics-out", default=None,
                     help="metrics snapshot of the spec-enabled pass")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     report = _measure(args)
     with open(args.out, "w") as f:
         json.dump(report, f, indent=1)

@@ -39,7 +39,7 @@ def main():
     sched = SchedulerConfig.from_args(args)
 
     # ---- real execution: continuous batching across three tenants ----
-    cfg, _, zoo = build_demo_zoo(seed=0)
+    cfg, zoo = build_demo_zoo(seed=0)
     engine = BlockEngine(zoo, max_len=64, config=EngineConfig(
         policy=sched.policy,
         speculation=sched.speculation,

@@ -9,7 +9,7 @@ layer, split into attention/ffn only when an adapter forces it (Fig. 11).
 from __future__ import annotations
 
 import hashlib
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import List, Optional, Tuple
 
 import jax
@@ -236,7 +236,7 @@ def block_decode_paged(block: Block, x, k_pages, v_pages, block_tables,
     """One-token step over a shared paged KV pool (DESIGN.md §2).
 
     x: (B, 1, D) hidden states (or token ids for embed blocks);
-    k_pages/v_pages: (P, page_size, KVH, hd) pool slabs; block_tables:
+    k_pages/v_pages: (P, KVH, page_size, hd) pool slabs; block_tables:
     (B, n) page ids per sequence; kv_len: (B,) tokens already cached.
 
     Writes the new token's K/V into the pool and attends over the pages via
@@ -297,6 +297,26 @@ def chain_signature(steps) -> Tuple:
     the same computation and can share one fused megastep."""
     return tuple((block.id, tuple(a.id for a in adapters))
                  for block, adapters in steps)
+
+
+def chain_params(steps) -> Tuple:
+    """The weights of a resolved chain as one pytree, in hop order.
+
+    Jitted chain functions take these as an argument: weights a jitted
+    function closes over are baked into its program as constants, which at
+    published widths means gigabytes of literals, a device copy of every
+    weight per executable, and a compile-cache key that hashes weights."""
+    return tuple((block.params, tuple(a.params for a in adapters))
+                 for block, adapters in steps)
+
+
+def bind_params(steps, params):
+    """``steps`` with every block's params replaced by the matching entry
+    of ``params`` (laid out as ``chain_params`` builds it) — inside a jitted
+    chain function, its traced weight arguments."""
+    return [(replace(block, params=p),
+             tuple(replace(a, params=q) for a, q in zip(adapters, qs)))
+            for (block, adapters), (p, qs) in zip(steps, params)]
 
 
 def _chain_step_fused(steps, pool_index, tokens, pools_k, pools_v, tables,

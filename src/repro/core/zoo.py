@@ -29,8 +29,12 @@ DEDUP_THRESHOLD = 0.995   # parametric: treat as the same block
 EQUIV_THRESHOLD = 0.98    # paper §7.1: adaptive-serving equivalence
 
 
-def _layer_params(stacked: dict, i: int) -> dict:
-    return jax.tree.map(lambda x: x[i], stacked)
+def _layer_params(layers, i: int) -> dict:
+    """Layer ``i``'s weights from stacked ``(L, ...)`` leaves or from a
+    per-layer list of trees."""
+    if isinstance(layers, (list, tuple)):
+        return layers[i]
+    return jax.tree.map(lambda x: x[i], layers)
 
 
 @dataclass

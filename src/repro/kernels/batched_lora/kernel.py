@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _lora_kernel(tile_groups, x_ref, w_ref, a_ref, b_ref, o_ref,
                  *, scaling: float):
@@ -75,7 +71,7 @@ def batched_lora_matmul(x, w, a, b, tile_groups, *, bt: int = 128,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, F), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(tile_groups, x, w, a, b)

@@ -1,11 +1,14 @@
 """Paged decode attention (TPU Pallas) — BlockLLM's KV-cache layer.
 
 PagedAttention (vLLM) adapted to TPU (DESIGN.md §2): KV lives in HBM page
-pools ``(num_pages, page_size, KVH, hd)``; each sequence owns a row of the
-``block_tables``.  The page table is a **scalar-prefetch** operand
-(PrefetchScalarGridSpec) so the BlockSpec index_map can chase page pointers
-at DMA-issue time — whole pages stream HBM->VMEM, page_size is chosen
-MXU/lane aligned (multiple of 128 recommended on the fused (page, hd) tile).
+pools laid out head-major, ``(num_pages, KVH, page_size, hd)``; each
+sequence owns a row of the ``block_tables``.  The page table is a
+**scalar-prefetch** operand (PrefetchScalarGridSpec) so the BlockSpec
+index_map can chase page pointers at DMA-issue time.  Head-major pages make
+each grid step's K/V block the slab's whole trailing ``(page_size, hd)``
+tile: Mosaic requires a block's last two dims to be (8, 128)-divisible or
+equal to the array's, which a ``(page_size, 1, hd)`` slice of a
+page-major slab is not.
 
 Grid: (B, KVH, pages_per_seq); the page dim is innermost/"arbitrary" so the
 online-softmax scratch persists across a sequence's pages.
@@ -21,10 +24,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 
 def _paged_kernel(block_tables, seq_lens,  # scalar-prefetch
@@ -46,8 +45,8 @@ def _paged_kernel(block_tables, seq_lens,  # scalar-prefetch
     @pl.when(page_start < seq_len)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (page_size, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)  # (page_size, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale  # (G, page_size)
@@ -71,13 +70,13 @@ def _paged_kernel(block_tables, seq_lens,  # scalar-prefetch
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
                     *, sm_scale: float | None = None,
                     interpret: bool = False):
-    """q: (B, Hq, hd); k_pages/v_pages: (num_pages, page_size, KVH, hd);
+    """q: (B, Hq, hd); k_pages/v_pages: (num_pages, KVH, page_size, hd);
     block_tables: (B, pages_per_seq) int32; seq_lens: (B,) int32.
 
     Returns (B, Hq, hd).
     """
     B, Hq, hd = q.shape
-    num_pages, page_size, KVH, _ = k_pages.shape
+    num_pages, KVH, page_size, _ = k_pages.shape
     assert Hq % KVH == 0
     G = Hq // KVH
     pages_per_seq = block_tables.shape[1]
@@ -97,10 +96,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
         in_specs=[
             pl.BlockSpec((1, 1, G, hd),
                          lambda b, h, i, bt, sl: (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, h, i, bt, sl: (bt[b, i], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, h, i, bt, sl: (bt[b, i], 0, h, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b, h, i, bt, sl: (bt[b, i], h, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b, h, i, bt, sl: (bt[b, i], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd),
                                lambda b, h, i, bt, sl: (b, h, 0, 0)),
@@ -114,7 +113,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, seq_lens, qg, k_pages, v_pages)

@@ -22,14 +22,15 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
 def write_token_to_pages(k_pages, v_pages, block_tables, positions, k_new, v_new):
     """Scatter one token per sequence into its page pool.
 
-    k_new/v_new: (B, KVH, hd); positions: (B,) absolute token index.
+    k_pages/v_pages: (P, KVH, page_size, hd); k_new/v_new: (B, KVH, hd);
+    positions: (B,) absolute token index.
     """
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     page_idx = block_tables[jnp.arange(block_tables.shape[0]),
                             positions // page_size]
     slot = positions % page_size
-    k_pages = k_pages.at[page_idx, slot].set(k_new.astype(k_pages.dtype))
-    v_pages = v_pages.at[page_idx, slot].set(v_new.astype(v_pages.dtype))
+    k_pages = k_pages.at[page_idx, :, slot].set(k_new.astype(k_pages.dtype))
+    v_pages = v_pages.at[page_idx, :, slot].set(v_new.astype(v_pages.dtype))
     return k_pages, v_pages
 
 

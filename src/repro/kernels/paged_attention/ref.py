@@ -6,14 +6,17 @@ import jax.numpy as jnp
 
 
 def paged_attention_ref(q, k_pages, v_pages, block_tables, seq_lens):
-    """q: (B, Hq, hd); pages: (P, page, KVH, hd); block_tables: (B, n)."""
+    """q: (B, Hq, hd); pages: (P, KVH, page, hd); block_tables: (B, n)."""
     B, Hq, hd = q.shape
-    _, page, KVH, _ = k_pages.shape
+    _, KVH, page, _ = k_pages.shape
     n = block_tables.shape[1]
     G = Hq // KVH
-    # gather each sequence's pages -> dense (B, n*page, KVH, hd)
-    k = k_pages[block_tables].reshape(B, n * page, KVH, hd)
-    v = v_pages[block_tables].reshape(B, n * page, KVH, hd)
+
+    def dense(pages):  # gather each sequence's pages -> (B, n*page, KVH, hd)
+        return pages[block_tables].transpose(0, 1, 3, 2, 4).reshape(
+            B, n * page, KVH, hd)
+
+    k, v = dense(k_pages), dense(v_pages)
     qg = q.reshape(B, KVH, G, hd)
     s = jnp.einsum("bhgd,bshd->bhgs", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) / math.sqrt(hd)

@@ -5,7 +5,8 @@ Two backends, one interface (submit / step / drain):
     # paper §7 evaluation on the modeled 12-device cluster
     PYTHONPATH=src python -m repro.launch.serve --backend sim --apps 20
 
-    # real JAX execution: continuous batching on the laptop-scale demo zoo
+    # real JAX execution: continuous batching on the demo zoo, at the
+    # width of any registered dense config (--arch, default blockllm-demo)
     PYTHONPATH=src python -m repro.launch.serve --backend real --requests 8
 
 Scheduler flags are generated straight from ``SchedulerConfig`` fields
@@ -46,13 +47,14 @@ def run_sim(args) -> dict:
 
 
 def run_real(args) -> dict:
+    import jax
     import numpy as np
 
     from repro.serving.api import ServeRequest
     from repro.serving.demo import build_demo_zoo
     from repro.serving.engine import BlockEngine, EngineConfig
 
-    cfg, _, zoo = build_demo_zoo(seed=0)
+    cfg, zoo = build_demo_zoo(seed=0, arch=args.arch)
     # engine-side §5.2 speculation rides the shared SchedulerConfig flags:
     # --speculation/--no-speculation, --spec-lookahead, --spec-prune-ratio,
     # --spec-min-accept toggle the real draft-verify decode path here
@@ -91,7 +93,11 @@ def run_real(args) -> dict:
     if getattr(args, "metrics_out", None):
         engine.write_metrics(args.metrics_out)
     stats = dict(engine.stats)
+    dev = jax.devices()[0]
     return {
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
+        "arch": cfg.name,
         "completed": len(results),
         "generated_tokens": gen_tokens,
         "wall_s": round(dt, 3),
@@ -120,6 +126,9 @@ def main():
     ap.add_argument("--duration", type=float, default=600.0)
     ap.add_argument("--gen-len", type=int, default=16)
     ap.add_argument("--max-len", type=int, default=64)
+    ap.add_argument("--arch", default="blockllm-demo",
+                    help="registered config the real backend's zoo is "
+                         "built at (random weights)")
     # observability artifacts (DESIGN.md §8), both backends
     ap.add_argument("--trace-out", default=None,
                     help="write Chrome trace_event JSON of the run")
@@ -131,6 +140,10 @@ def main():
     SchedulerConfig.add_args(ap)
     args = ap.parse_args()
 
+    if args.backend == "real":
+        from repro.launch.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     metrics = run_sim(args) if args.backend == "sim" else run_real(args)
     print(json.dumps({k: (round(v, 4) if isinstance(v, float) else v)
                       for k, v in metrics.items()}, indent=1))

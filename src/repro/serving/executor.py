@@ -26,10 +26,12 @@ import numpy as np
 from repro.core.blocks import (
     Block,
     apply_block,
+    bind_params,
     block_decode_paged,
     block_prefill_raw,
     chain_decode_fused,
     chain_decode_spec_fused,
+    chain_params,
     chain_prefill_fused,
     chain_signature,
 )
@@ -125,6 +127,7 @@ class BlockExecutor:
         if fn is not None:
             return fn
         impl = self.attn_impl
+        hop = [(block, adapters)]
         if block.has_kv:
             if block.cfg.sliding_window:
                 raise NotImplementedError(
@@ -132,17 +135,20 @@ class BlockExecutor:
 
             # donate the pool slabs: the update is a one-token scatter, so
             # XLA can write in place instead of copying the whole pool
-            @functools.partial(jax.jit, donate_argnums=(1, 2))
-            def fn(x, k_pages, v_pages, tables, kv_len):
-                return block_decode_paged(block, x, k_pages, v_pages,
-                                          tables, kv_len, adapters=adapters,
+            @functools.partial(jax.jit, donate_argnums=(2, 3))
+            def fn(params, x, k_pages, v_pages, tables, kv_len):
+                (b, ads), = bind_params(hop, params)
+                return block_decode_paged(b, x, k_pages, v_pages, tables,
+                                          kv_len, adapters=ads,
                                           attn_impl=impl)
         else:
 
             @jax.jit
-            def fn(x):
-                return apply_block(block, x, adapters=adapters)
+            def fn(params, x):
+                (b, ads), = bind_params(hop, params)
+                return apply_block(b, x, adapters=ads)
 
+        fn = functools.partial(fn, chain_params(hop))
         self._block_fns[key] = fn
         return fn
 
@@ -152,11 +158,14 @@ class BlockExecutor:
         key = (block.id, tuple(a.id for a in adapters))
         fn = self._prefill_fns.get(key)
         if fn is None:
+            hop = [(block, adapters)]
 
             @jax.jit
-            def fn(x):
-                return block_prefill_raw(block, x, adapters=adapters)
+            def fn(params, x):
+                (b, ads), = bind_params(hop, params)
+                return block_prefill_raw(b, x, adapters=ads)
 
+            fn = functools.partial(fn, chain_params(hop))
             self._prefill_fns[key] = fn
         return fn
 
@@ -205,9 +214,11 @@ class BlockExecutor:
         if fn is None:
 
             @jax.jit
-            def fn(tok, lens):
-                return chain_prefill_fused(steps, tok, lens)
+            def fn(params, tok, lens):
+                return chain_prefill_fused(bind_params(steps, params), tok,
+                                           lens)
 
+            fn = functools.partial(fn, chain_params(steps))
             self._chain_prefill_fns[sig] = fn
         return fn
 
@@ -266,13 +277,13 @@ class BlockExecutor:
         impl = self.attn_impl
         pool_keys, pool_index = self._pool_layout(steps)
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def fn(tok, pools_k, pools_v, tables, kv_len):
-            return chain_decode_fused(steps, pool_index, tok, pools_k,
-                                      pools_v, tables, kv_len,
+        @functools.partial(jax.jit, donate_argnums=(2, 3))
+        def fn(params, tok, pools_k, pools_v, tables, kv_len):
+            return chain_decode_fused(bind_params(steps, params), pool_index,
+                                      tok, pools_k, pools_v, tables, kv_len,
                                       attn_impl=impl)
 
-        out = (fn, tuple(pool_keys))
+        out = (functools.partial(fn, chain_params(steps)), tuple(pool_keys))
         self._fused_fns[sig] = out
         return out
 
@@ -293,13 +304,16 @@ class BlockExecutor:
             raise ValueError(
                 "surrogate chain must share the full chain's KV-pool layout")
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def fn(tok, pools_k, pools_v, tables, kv_len, budget):
+        @functools.partial(jax.jit, donate_argnums=(3, 4))
+        def fn(params, sur_params, tok, pools_k, pools_v, tables, kv_len,
+               budget):
             return chain_decode_spec_fused(
-                steps, sur_steps, pool_index, tok, pools_k, pools_v,
-                tables, kv_len, budget, lookahead=lookahead, attn_impl=impl)
+                bind_params(steps, params), bind_params(sur_steps, sur_params),
+                pool_index, tok, pools_k, pools_v, tables, kv_len, budget,
+                lookahead=lookahead, attn_impl=impl)
 
-        out = (fn, tuple(pool_keys))
+        out = (functools.partial(fn, chain_params(steps),
+                                 chain_params(sur_steps)), tuple(pool_keys))
         self._spec_fns[key] = out
         return out
 

@@ -1,8 +1,8 @@
 """KV manager: slot-based paged KV pools shared across chains, with
 preemption (DESIGN.md §2).
 
-One ``KVPool`` per (kv_heads, head_dim, dtype) signature holds two page
-slabs ``(num_pages, page_size, KVH, hd)`` for K and V.  Every
+One ``KVPool`` per (kv_heads, head_dim, dtype) signature holds two
+head-major page slabs ``(num_pages, KVH, page_size, hd)`` for K and V.  Every
 attention-bearing chain step of every in-flight request owns a run of
 page ids (a *slot*) carved out of the same slab, so requests from
 different apps — and the shared foundation blocks they batch on — draw
@@ -47,7 +47,7 @@ class KVPool:
         self.num_pages = num_pages
         self.kv_heads = kv_heads
         self.head_dim = head_dim
-        shape = (num_pages, page_size, kv_heads, head_dim)
+        shape = (num_pages, kv_heads, page_size, head_dim)
         self.k_pages = jnp.zeros(shape, dtype)
         self.v_pages = jnp.zeros(shape, dtype)
         # page 0 reserved (TRASH_PAGE); never handed out
@@ -142,6 +142,7 @@ class KVPool:
             v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         kp = k_r[0].reshape(npages, self.page_size, *k_r.shape[2:])
         vp = v[0].reshape(npages, self.page_size, *v.shape[2:])
+        kp, vp = kp.transpose(0, 2, 1, 3), vp.transpose(0, 2, 1, 3)
         idx = jnp.asarray(slot.pages[:npages], jnp.int32)
         self.k_pages = self.k_pages.at[idx].set(kp.astype(self.k_pages.dtype))
         self.v_pages = self.v_pages.at[idx].set(vp.astype(self.v_pages.dtype))

@@ -35,7 +35,7 @@ def _mixed_requests(cfg, n=8, seed=0, gen_lens=(4, 5, 6)):
 def test_batched_matches_sequential_greedy(demo):
     from repro.serving.engine import BlockEngine
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     engine = BlockEngine(zoo, max_len=64)
     reqs = _mixed_requests(cfg, n=8)
     rids = [engine.submit(r) for r in reqs]
@@ -62,7 +62,7 @@ def test_step_granularity_and_interleaved_submission(demo):
     produce the same tokens."""
     from repro.serving.engine import BlockEngine
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     engine = BlockEngine(zoo, max_len=64)
     reqs = _mixed_requests(cfg, n=4, seed=1, gen_lens=(6,))
     first = [engine.submit(r) for r in reqs[:2]]
@@ -111,7 +111,7 @@ def test_kv_pool_alloc_free_reuse():
 def test_engine_pool_recycled_across_requests(demo):
     from repro.serving.engine import BlockEngine
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     engine = BlockEngine(zoo, max_len=64)
     for r in _mixed_requests(cfg, n=4, seed=2):
         engine.submit(r)
@@ -131,7 +131,7 @@ def test_engine_pool_recycled_across_requests(demo):
 def test_engine_admission_blocks_on_full_pool(demo):
     from repro.serving.engine import BlockEngine, EngineConfig
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     # pool sized for ~one request per attention step at a time
     engine = BlockEngine(zoo, max_len=32,
                          config=EngineConfig(num_pages=1 + 2 * 4 * 2,
@@ -156,7 +156,7 @@ def test_both_backends_implement_server(demo):
         build_serving_config,
     )
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     assert isinstance(BlockEngine(zoo), Server)
     sim = Simulation(build_serving_config(n_apps=4), SchedulerConfig())
     assert isinstance(sim, Server)

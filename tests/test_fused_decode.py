@@ -55,7 +55,7 @@ def _engines(zoo, max_len=64, **kw):
 def test_fused_matches_per_hop_small(demo):
     """Two same-app requests with ragged prompts: one fused group, exact
     token parity with the per-hop oracle (fast smoke-tier case)."""
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     fused, hop = _engines(zoo)
     reqs = _requests(cfg, n=2, seed=7, gen_lens=(3,))
     reqs[1].app = reqs[0].app  # single signature group
@@ -74,7 +74,7 @@ def test_fused_matches_per_hop_small(demo):
 def test_fused_matches_per_hop_mixed_apps(demo):
     """Eight mixed-app mixed-gen_len requests: several signature groups,
     membership churn as short requests finish; still token-exact."""
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     fused, hop = _engines(zoo)
     reqs = _requests(cfg, n=8, seed=13)
     got = _serve(fused, reqs)
@@ -92,7 +92,7 @@ def test_fused_matches_per_hop_mixed_apps(demo):
 def test_fused_interleaved_submission(demo):
     """Requests joining mid-flight re-form fused groups (old DecodeStates
     retire, host state stays exact)."""
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     fused, hop = _engines(zoo)
     reqs = _requests(cfg, n=4, seed=17, gen_lens=(6,))
     first = [fused.submit(r) for r in reqs[:2]]
@@ -112,7 +112,7 @@ def test_fused_preemption_token_exact(demo, strategy):
     """Preempting a device-resident request mid-stream syncs its group
     before the spill/recalc touches host state; both §5.1 strategies
     resume token-exact under the fused path."""
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     fused, hop = _engines(zoo)
     reqs = _requests(cfg, n=3, seed=19)
     rids = [fused.submit(r) for r in reqs]
@@ -136,7 +136,7 @@ def test_fused_preemption_token_exact(demo, strategy):
 def test_fused_interpret_attention_parity(demo):
     """The Pallas kernel in interpret mode feeds the fused megastep the
     same numbers as the reference attention: token-exact across impls."""
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     fused_ref, _ = _engines(zoo)
     fused_int, _ = _engines(zoo, attn_impl="interpret")
     reqs = _requests(cfg, n=2, seed=23, gen_lens=(3,))
@@ -156,7 +156,7 @@ def test_generate_gen_len_zero(demo):
     instead of crashing on np.stack over missing distributions."""
     from repro.serving.engine import BlockEngine
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     engine = BlockEngine(zoo, max_len=64)
     rng = np.random.RandomState(29)
     prompts = rng.randint(0, cfg.vocab_size, size=(3, 12)).astype(np.int32)
@@ -178,7 +178,7 @@ def test_table_cache_bounded_under_churn(demo):
     every step, the bound holds, and tokens stay exact."""
     from repro.serving.engine import BlockEngine, EngineConfig
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     engine = BlockEngine(zoo, max_len=64, config=EngineConfig(fused=False))
     engine.executor.table_cache_max = 2
     reqs = _requests(cfg, n=6, seed=31)  # mixed gen_lens: membership churn
@@ -203,7 +203,7 @@ def test_fused_fn_rejects_sliding_window(demo):
     from repro.core.blocks import chain_signature
     from repro.serving.engine import BlockEngine
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     engine = BlockEngine(zoo, max_len=64)
     steps = engine._steps(zoo.chains["base"], None)[0]
     swapped = []

@@ -48,8 +48,8 @@ def test_paged_attention(B, Hq, KVH, hd, page, npages_per_seq, dtype):
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     total_pages = B * npages_per_seq + 2
     q = jax.random.normal(ks[0], (B, Hq, hd), dtype)
-    k_pages = jax.random.normal(ks[1], (total_pages, page, KVH, hd), dtype)
-    v_pages = jax.random.normal(ks[2], (total_pages, page, KVH, hd), dtype)
+    k_pages = jax.random.normal(ks[1], (total_pages, KVH, page, hd), dtype)
+    v_pages = jax.random.normal(ks[2], (total_pages, KVH, page, hd), dtype)
     # each sequence owns a disjoint, shuffled set of pages
     perm = rng.permutation(B * npages_per_seq) + 2
     block_tables = jnp.asarray(perm.reshape(B, npages_per_seq), jnp.int32)
@@ -91,7 +91,7 @@ def test_page_pool_roundtrip():
 
     B, KVH, hd, page, nps = 2, 2, 64, 128, 2
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
-    k_pages = jnp.zeros((B * nps + 1, page, KVH, hd), jnp.float32)
+    k_pages = jnp.zeros((B * nps + 1, KVH, page, hd), jnp.float32)
     v_pages = jnp.zeros_like(k_pages)
     block_tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     # fill 130 tokens of each sequence token-by-token, then attend

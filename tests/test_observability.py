@@ -195,7 +195,7 @@ def test_trace_invariants_under_preemption_churn(demo, strategy):
     and preempt/readmit events pair up exactly."""
     from repro.serving.engine import BlockEngine
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     engine = BlockEngine(zoo, max_len=64)
     reqs = _requests(cfg, n=3, seed=31)
     rids = [engine.submit(r) for r in reqs]
@@ -232,7 +232,7 @@ def test_metrics_reconcile_with_results(demo):
     what ``drain`` actually handed back."""
     from repro.serving.engine import BlockEngine
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     engine = BlockEngine(zoo, max_len=64)
     reqs = _requests(cfg, n=4, seed=32, gen_len=5)
     rids = [engine.submit(r) for r in reqs]

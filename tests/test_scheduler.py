@@ -156,7 +156,7 @@ def _backends(demo, policy):
         build_serving_config,
     )
 
-    _, _, zoo = demo
+    _, zoo = demo
     engine = BlockEngine(zoo, config=EngineConfig(policy=policy))
     sim = Simulation(build_serving_config(n_apps=4),
                      SchedulerConfig(policy=policy))
@@ -218,7 +218,7 @@ def test_forced_preemption_token_exact(demo, strategy):
     run exactly — for both §5.1 readmission strategies."""
     from repro.serving.engine import BlockEngine
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     engine = BlockEngine(zoo, max_len=64)
     reqs = _requests(cfg, n=3, seed=11)
     rids = [engine.submit(r) for r in reqs]
@@ -245,7 +245,7 @@ def test_pressure_preemption_under_priority_policy(demo):
     when the pool cannot hold both; both finish token-exact."""
     from repro.serving.engine import BlockEngine, EngineConfig
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     # pool sized for exactly one resident request (4 attn steps x 2 pages)
     engine = BlockEngine(zoo, max_len=32,
                          config=EngineConfig(num_pages=9, page_size=16,
@@ -272,7 +272,7 @@ def test_fcfs_pressure_serializes_without_preemption(demo):
     are never ranked below an older head)."""
     from repro.serving.engine import BlockEngine, EngineConfig
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     engine = BlockEngine(zoo, max_len=32,
                          config=EngineConfig(num_pages=9, page_size=16))
     reqs = _requests(cfg, n=3, seed=23, gen_len=4)
@@ -292,7 +292,7 @@ def test_gen_len_zero_completes_at_admission(demo):
     from repro.serving.api import ServeRequest
     from repro.serving.engine import BlockEngine
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     engine = BlockEngine(zoo, max_len=64)
     rng = np.random.RandomState(31)
     prompt = rng.randint(0, cfg.vocab_size, size=12).astype(np.int32)

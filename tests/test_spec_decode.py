@@ -61,7 +61,7 @@ def test_forced_accept_token_exact(demo):
     weights, identical order), so every draft equals the verify argmax:
     all attempts hit, multiple tokens commit per step, and the stream is
     token-exact vs the spec-off engine."""
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     spec, plain = _spec_pair(zoo, spec_prune_ratio=0.0)
     reqs = _requests(cfg, n=2, seed=7, gen_lens=(8,))
     got = _serve(spec, reqs)
@@ -81,7 +81,7 @@ def test_forced_accept_token_exact(demo):
 def test_forced_accept_near_budget_clamp(demo):
     """gen_len barely above the lookahead: the per-lane budget clamp must
     stop perfect drafts from committing past the generation budget."""
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     spec, plain = _spec_pair(zoo, spec_prune_ratio=0.0, spec_lookahead=4)
     reqs = _requests(cfg, n=1, seed=11, gen_lens=(4,))
     got = _serve(spec, reqs)
@@ -119,7 +119,7 @@ def test_forced_reject_token_exact(demo):
     """Every draft rejected: each spec step commits exactly one token (the
     verified pending token), output stays token-exact, and the hit counter
     stays at zero."""
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     spec, plain = _spec_pair(zoo, spec_min_accept=0.0)  # gate never trips
     _negate_lm_head(spec, "base")
     reqs = _requests(cfg, n=2, seed=13, gen_lens=(6,))
@@ -138,7 +138,7 @@ def test_reject_gate_disables_then_retries(demo):
     cooldown re-enables it for a fresh trial ``spec_retry_steps`` later."""
     from repro.core.blocks import chain_signature
 
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     spec = _engine(zoo, speculation=True, spec_min_accept=0.5,
                    spec_ema_alpha=0.5, spec_retry_steps=3)
     ss = _negate_lm_head(spec, "base")
@@ -164,7 +164,7 @@ def test_mixed_apps_token_exact(demo):
     """Six mixed-app mixed-gen_len requests at the default prune ratio:
     partial accepts, speculation-aware grouping, membership churn as short
     requests finish — token streams stay identical to spec-off."""
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     spec, plain = _spec_pair(zoo)
     reqs = _requests(cfg, n=6, seed=19, gen_lens=(5, 9, 12),
                      apps=("base", "vicuna", "app-lora"))
@@ -190,7 +190,7 @@ def test_preemption_mid_speculation_token_exact(demo, strategy):
     """Preempting a lane whose group has uncommitted spec buffers syncs the
     exact per-lane commit counts to host first; both §5.1 readmit paths
     resume token-exact, and the churn gate pauses speculation."""
-    cfg, _, zoo = demo
+    cfg, zoo = demo
     spec, plain = _spec_pair(zoo, spec_churn_steps=2)
     reqs = _requests(cfg, n=3, seed=23, gen_lens=(10, 12, 14))
     rids = [spec.submit(r) for r in reqs]
@@ -220,7 +220,7 @@ def test_surrogate_cache_eviction(demo):
     """The zoo's surrogate cache is a bounded LRU keyed by (parent id,
     ratio, prune_kv): hits return the cached id, eviction removes the
     surrogate block from the zoo, and a re-request rebuilds it."""
-    _, _, zoo = demo
+    _, zoo = demo
     layer_ids = [s.block_id for s in zoo.chains["base"].steps
                  if "w_gate" in zoo.blocks[s.block_id].params]
     assert len(layer_ids) >= 3
@@ -265,7 +265,7 @@ def test_spec_stat_keys_aligned(demo):
         build_serving_config,
     )
 
-    _, _, zoo = demo
+    _, zoo = demo
     engine = _engine(zoo, speculation=True)
     sim = Simulation(build_serving_config(n_foundations=1, n_apps=2),
                      SchedulerConfig())

@@ -2,6 +2,7 @@
 -> evaluation metrics, exercising the whole public API surface."""
 import jax
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import SHAPES, get_config, get_reduced_config, list_configs
 
@@ -77,7 +78,8 @@ def test_dryrun_cell_on_tiny_mesh():
     from repro.launch.hlo_analysis import cost_analysis_dict
     from repro.launch.steps import build_cell
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = get_reduced_config("tinyllama-1.1b")
     shape = SHAPES["train_4k"]
     shape = type(shape)("tiny_train", 32, 2, "train")

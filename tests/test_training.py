@@ -4,6 +4,7 @@ compression, checkpoint/restart + elastic resharding."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.configs import get_reduced_config
 from repro.data.pipeline import DataConfig, TokenPipeline
@@ -90,7 +91,7 @@ def test_checkpoint_elastic_reshard(tmp_path):
             "b": jnp.ones((8,), jnp.float32)}
     ck = Checkpointer(str(tmp_path / "el"))
     ck.save(1, tree, blocking=True)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
     shardings = {"w": NamedSharding(mesh, P("model", None)),
                  "b": NamedSharding(mesh, P(None))}
     restored = ck.restore(tree, shardings=shardings)
