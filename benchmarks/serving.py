@@ -111,7 +111,7 @@ def bench_batched(cfg, zoo, engine, args, seed):
     # per-block batch occupancy: mean lanes per group call vs the §5.2 cap
     hb_count = h_batch.count - hb_count0
     bb_mean = (h_batch.total - hb_sum0) / hb_count if hb_count else 0.0
-    max_batch = engine.metrics.gauge("max_block_batch").value or 1
+    max_batch = engine.config.max_block_batch
     dispatch["block_batch_mean"] = round(bb_mean, 2)
     dispatch["block_util_frac"] = round(bb_mean / max_batch, 3)
     return toks, dt, results, dispatch

@@ -4,8 +4,9 @@ Two halves, both shared by the real-execution ``BlockEngine`` and the
 discrete-event ``Simulation``:
 
 - ``trace``: per-request lifecycle event logs (submit → admit → prefill →
-  per-step decode → preempt/spill/readmit → finish) with derived phase
-  spans and Chrome ``trace_event`` export for chrome://tracing;
+  preempt/spill/readmit → finish) with derived phase spans and Chrome
+  ``trace_event`` export for chrome://tracing, plus program spans
+  (``Tracer.span``) on the profiler's clock;
 - ``metrics``: a typed registry of counters / gauges / histograms that
   replaces the ad-hoc ``stats`` dicts, so discrete-event and real runs
   emit comparable reports.

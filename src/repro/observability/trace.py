@@ -1,8 +1,8 @@
 """Per-request lifecycle tracing (DESIGN.md §8).
 
 Every served request accumulates timestamped lifecycle *events*
-(``submit`` → ``admit`` → ``prefill`` → per-step ``decode_step`` →
-``preempt``/``spill``/``readmit`` → ``finish``); contiguous phase *spans*
+(``submit`` → ``admit`` → ``prefill`` → ``preempt``/``spill``/``readmit``
+→ ``finish``); contiguous phase *spans*
 are derived from the boundary events, so by construction the span chain
 covers submit → finish with no gaps:
 
@@ -19,7 +19,11 @@ plane — the same span algebra serves both.
 
 ``chrome_trace`` renders traces as Chrome ``trace_event`` JSON (one
 thread per request, ``X`` complete events per span, instants for
-spill/restore/decode steps) loadable in chrome://tracing or Perfetto.
+spill/restore/spec events) loadable in chrome://tracing or Perfetto.
+
+``Tracer.span`` marks the serving layers' own work (``<layer>.<what>``,
+e.g. ``exec.dispatch``) on the profiler's clock, so a ``jax.profiler``
+trace shows which host phase was running while the device sat idle.
 """
 from __future__ import annotations
 
@@ -27,6 +31,8 @@ import json
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # events that end one phase span and start the next
 BOUNDARY_EVENTS = ("submit", "admit", "prefill", "preempt", "readmit",
@@ -147,6 +153,15 @@ class Tracer:
         self.global_spans.append((name, t0, t1, meta))
         if len(self.global_spans) > self.max_traces:
             del self.global_spans[: len(self.global_spans) // 2]
+
+    @staticmethod
+    def span(name: str, **meta) -> TraceAnnotation:
+        """Context manager over one program span, ``<layer>.<what>``
+        (DESIGN.md §8).  It is a ``jax.profiler.TraceAnnotation``, so it
+        lands on the device trace's clock in a profiled run and costs one
+        ``TraceMe`` when no profiler runs.  ``meta`` becomes the event's
+        stats in the trace."""
+        return TraceAnnotation(name, **meta)
 
     def _evict_finished(self) -> None:
         victims = [rid for rid, tr in self.traces.items()

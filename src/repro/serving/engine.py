@@ -88,6 +88,7 @@ class _ReqState:
     gen_len: int
     prompt_len: int
     slot_tokens: int = 0        # KV slot capacity (adds spec lookahead room)
+    kv_hops: int = 0            # attention hops: one KV slot each
     prompt_tokens: Optional[np.ndarray] = None  # kept for recompute-on-readmit
     adaptive_blocks_used: int = 0
     kv_len: int = 0             # tokens currently cached (prompt + decoded)
@@ -119,11 +120,14 @@ class BlockEngine(Server):
                      "recalc_readmits", "completed", "tokens_emitted",
                      "spec_attempts", "spec_hits"):
             self.metrics.counter(name)  # pre-register: snapshots start at 0
-        self.metrics.set_gauge("max_block_batch", c.max_block_batch)
         self.metrics.set_gauge("spec_accept_rate", 0.0)
         # legacy dict-shaped view: engine.stats[k] reads the counter values
         self.stats = self.metrics.counters_view()
         self._c_steps = self.metrics.counter("steps")
+        # pages held in the pools, and pages the resident requests' cached
+        # tokens fill, each summed over steps (DESIGN.md §8)
+        self._c_kv_reserved = self.metrics.counter("kv_page_steps_reserved")
+        self._c_kv_live = self.metrics.counter("kv_page_steps_live")
         self._h_step_wall = self.metrics.histogram("step_wall_s")
         self.scheduler = Scheduler(policy=c.policy, tracer=self.tracer,
                                    metrics=self.metrics)
@@ -207,22 +211,35 @@ class BlockEngine(Server):
         return req.rid
 
     def step(self) -> Optional[List[ServeResult]]:
-        t0 = time.perf_counter()
-        self._admit()
-        early, self._early = self._early, []
-        if not self.active:
-            if early:
-                return early
-            return None if not self.scheduler.waiting else []
-        self._c_steps.inc()
-        out = early + self._decode_step()
-        self.metrics.set_gauge("active", len(self.active))
-        t1 = time.perf_counter()
-        self._h_step_wall.observe(t1 - t0)
-        self.tracer.global_span("engine_step", t0, t1,
-                                active=len(self.active),
-                                finished=len(out))
-        return out
+        with self.tracer.span("engine.step"):
+            t0 = time.perf_counter()
+            self._count_kv_pages()
+            self._admit()
+            early, self._early = self._early, []
+            if not self.active:
+                if early:
+                    return early
+                return None if not self.scheduler.waiting else []
+            self._c_steps.inc()
+            out = early + self._decode_step()
+            self.metrics.set_gauge("active", len(self.active))
+            t1 = time.perf_counter()
+            self._h_step_wall.observe(t1 - t0)
+            self.tracer.global_span("engine_step", t0, t1,
+                                    active=len(self.active),
+                                    finished=len(out))
+            return out
+
+    def _count_kv_pages(self) -> None:
+        """Add this step's reserved pages (every pool's pages in use) and
+        live pages (each resident request's cached tokens, in whole pages,
+        on each attention hop) to their counters."""
+        self._c_kv_reserved.inc(sum(p.used_pages
+                                    for p in self.kv.pools.values()))
+        ex, page = self.executor, self.config.page_size
+        self._c_kv_live.inc(sum(
+            s.kv_hops * max(1, -(-(s.kv_len + ex.buffered(s.rid)) // page))
+            for s in self.active))
 
     def drain(self) -> List[ServeResult]:
         out: List[ServeResult] = []
@@ -262,11 +279,13 @@ class BlockEngine(Server):
             steps, self._slot_tokens(entry.prompt_len, entry.gen_len))
 
     def _admit(self):
-        admitted = self.scheduler.admit(
-            fits=self._fits,
-            running=lambda: [self._entries[s.rid] for s in self.active],
-            preempt=(self._preempt_entry if self.config.preemption else None),
-            on_admit=self._place)
+        with self.tracer.span("sched.admit"):
+            admitted = self.scheduler.admit(
+                fits=self._fits,
+                running=lambda: [self._entries[s.rid] for s in self.active],
+                preempt=(self._preempt_entry if self.config.preemption
+                         else None),
+                on_admit=self._place)
         if self._pending_prefill:
             # batched multi-request prefill: slots were allocated per entry
             # during admission (so fits saw true occupancy); the compute
@@ -303,6 +322,7 @@ class BlockEngine(Server):
                           gen_len=entry.gen_len, prompt_len=entry.prompt_len,
                           slot_tokens=self._slot_tokens(entry.prompt_len,
                                                         entry.gen_len),
+                          kv_hops=sum(b.has_kv for b, _ in steps),
                           prompt_tokens=np.asarray(req.prompt_tokens),
                           adaptive_blocks_used=used_adaptive,
                           t_submit=t_submit)
@@ -310,10 +330,11 @@ class BlockEngine(Server):
             # reserve whole-lifetime slots now — the admission loop's next
             # fits() must see them — and defer the compute so co-admitted
             # requests prefill as one batched call per (chain, bucket)
-            for i, (block, _) in enumerate(steps):
-                if block.has_kv:
-                    _, pool = self.kv.pool_for(block)
-                    pool.alloc(state.rid, i, state.slot_tokens)
+            with self.tracer.span("kv.alloc"):
+                for i, (block, _) in enumerate(steps):
+                    if block.has_kv:
+                        _, pool = self.kv.pool_for(block)
+                        pool.alloc(state.rid, i, state.slot_tokens)
             self._pending_prefill.append(state)
         else:
             self.executor.prefill(state, req.prompt_tokens, self.kv)
@@ -471,44 +492,48 @@ class BlockEngine(Server):
     def _decode_step(self) -> List[ServeResult]:
         ex = self.executor
         cfg = self.config
+        span = self.tracer.span
         self._tick_spec_gates()
-        # split finished from still-running; a device-resident request has
-        # ex.buffered(rid) committed tokens not yet reflected in s.tokens
-        continuing: List[_ReqState] = []
-        finishing: List[_ReqState] = []
-        rem: Dict[int, int] = {}  # tokens still to commit (excl. pending)
-        for s in self.active:
-            done = len(s.tokens) + ex.buffered(s.rid)
-            rem[s.rid] = s.gen_len - done
-            (finishing if done + 1 >= s.gen_len else continuing).append(s)
         # a lane can speculate when its signature is enabled and it has
         # budget for at least one draft attempt (rem >= 3: the pending
         # token, one draft, and the final token that must stay pending)
         spec_on = (cfg.speculation and cfg.fused and self._spec_churn == 0)
+        rem: Dict[int, int] = {}  # tokens still to commit (excl. pending)
 
         def _eligible(s: _ReqState) -> bool:
             return (rem[s.rid] >= 3
                     and self._spec_state(chain_signature(s.steps),
                                          s.steps).enabled)
 
-        # partition the survivors into fused groups by full-chain signature
-        # (§5.2 batch cap applied chain-wide), refined by speculation
-        # eligibility so each group steps uniformly; chains the fused
-        # megastep cannot compile fall back to the per-hop dispatch path
-        fused_groups: List[List[_ReqState]] = []
-        hop_states: List[_ReqState] = []
-        if cfg.fused:
-            for g in self.scheduler.form_chain_groups(
-                    continuing, key_fn=lambda s: chain_signature(s.steps),
-                    max_batch=cfg.max_block_batch,
-                    subkey_fn=_eligible if spec_on else None):
-                try:
-                    ex.fused_fn(g[0].steps, chain_signature(g[0].steps))
-                    fused_groups.append(g)
-                except NotImplementedError:
-                    hop_states.extend(g)
-        else:
-            hop_states = continuing
+        with span("sched.form_groups"):
+            # split finished from still-running; a device-resident request
+            # has ex.buffered(rid) committed tokens not yet in s.tokens
+            continuing: List[_ReqState] = []
+            finishing: List[_ReqState] = []
+            for s in self.active:
+                done = len(s.tokens) + ex.buffered(s.rid)
+                rem[s.rid] = s.gen_len - done
+                (finishing if done + 1 >= s.gen_len
+                 else continuing).append(s)
+            # partition the survivors into fused groups by full-chain
+            # signature (§5.2 batch cap applied chain-wide), refined by
+            # speculation eligibility so each group steps uniformly; chains
+            # the fused megastep cannot compile fall back to the per-hop
+            # dispatch path
+            fused_groups: List[List[_ReqState]] = []
+            hop_states: List[_ReqState] = []
+            if cfg.fused:
+                for g in self.scheduler.form_chain_groups(
+                        continuing, key_fn=lambda s: chain_signature(s.steps),
+                        max_batch=cfg.max_block_batch,
+                        subkey_fn=_eligible if spec_on else None):
+                    try:
+                        ex.fused_fn(g[0].steps, chain_signature(g[0].steps))
+                        fused_groups.append(g)
+                    except NotImplementedError:
+                        hop_states.extend(g)
+            else:
+                hop_states = continuing
         # groups that changed membership (finish/admission) sync to host
         # here; identical groups keep their device-resident DecodeState
         ex.retire_states(keep=frozenset(
@@ -517,7 +542,8 @@ class BlockEngine(Server):
         results = []
         for s in finishing:
             s.tokens.append(s.next_token)
-            results.append(self._finish(s))
+            with span("engine.finish"):
+                results.append(self._finish(s))
         if finishing:
             ex.invalidate_tables()
         self.active = continuing
@@ -527,24 +553,19 @@ class BlockEngine(Server):
         # token (or, speculating, up to spec_lookahead tokens drafted by
         # the surrogate chain and verified exactly), sampling on device
         for g in fused_groups:
-            if spec_on and _eligible(g[0]):
-                self._spec_group_step(g, rem)
-            else:
-                ex.fused_step(g, self.kv)
+            with span("exec.dispatch", B=len(g), app=g[0].app):
+                if spec_on and _eligible(g[0]):
+                    self._spec_group_step(g, rem)
+                else:
+                    ex.fused_step(g, self.kv)
         if hop_states:
             # per-hop states emit host-side: the pending token lands in
             # s.tokens now and also seeds this step's chain walk
             for s in hop_states:
                 s.tokens.append(s.next_token)
-            self._run_hops(hop_states)
-        # one decode_step instant per in-flight request: each engine step
-        # advances every continuing request by at least one token (fused
-        # groups device-resident, spec groups by 1..lookahead, per-hop
-        # host-side), so the host-side dispatch timestamp is the per-step
-        # trace marker
-        t = time.perf_counter()
-        for s in continuing:
-            self.tracer.event(s.rid, "decode_step", t=t)
+            with span("exec.dispatch", B=len(hop_states),
+                      app=hop_states[0].app):
+                self._run_hops(hop_states)
         return results
 
     def _spec_group_step(self, g: List[_ReqState], rem: Dict[int, int]

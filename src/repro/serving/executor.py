@@ -35,7 +35,7 @@ from repro.core.blocks import (
     chain_prefill_fused,
     chain_signature,
 )
-from repro.observability.metrics import MetricsRegistry
+from repro.observability import MetricsRegistry, Tracer
 from repro.serving.kv_pool import KVManager
 
 
@@ -136,17 +136,19 @@ class BlockExecutor:
             # donate the pool slabs: the update is a one-token scatter, so
             # XLA can write in place instead of copying the whole pool
             @functools.partial(jax.jit, donate_argnums=(2, 3))
-            def fn(params, x, k_pages, v_pages, tables, kv_len):
+            def block_decode(params, x, k_pages, v_pages, tables, kv_len):
                 (b, ads), = bind_params(hop, params)
                 return block_decode_paged(b, x, k_pages, v_pages, tables,
                                           kv_len, adapters=ads,
                                           attn_impl=impl)
+            fn = block_decode
         else:
 
             @jax.jit
-            def fn(params, x):
+            def block_apply(params, x):
                 (b, ads), = bind_params(hop, params)
                 return apply_block(b, x, adapters=ads)
+            fn = block_apply
 
         fn = functools.partial(fn, chain_params(hop))
         self._block_fns[key] = fn
@@ -161,11 +163,11 @@ class BlockExecutor:
             hop = [(block, adapters)]
 
             @jax.jit
-            def fn(params, x):
+            def block_prefill(params, x):
                 (b, ads), = bind_params(hop, params)
                 return block_prefill_raw(b, x, adapters=ads)
 
-            fn = functools.partial(fn, chain_params(hop))
+            fn = functools.partial(block_prefill, chain_params(hop))
             self._prefill_fns[key] = fn
         return fn
 
@@ -207,18 +209,19 @@ class BlockExecutor:
             key = (chain_signature(s.steps), _bucket(s.prompt_len))
             groups.setdefault(key, []).append(s)
         for (sig, bucket), members in groups.items():
-            self._prefill_group(sig, bucket, members, kv)
+            with Tracer.span("exec.prefill", B=len(members), bucket=bucket):
+                self._prefill_group(sig, bucket, members, kv)
 
     def chain_prefill_fn(self, steps, sig):
         fn = self._chain_prefill_fns.get(sig)
         if fn is None:
 
             @jax.jit
-            def fn(params, tok, lens):
+            def chain_prefill(params, tok, lens):
                 return chain_prefill_fused(bind_params(steps, params), tok,
                                            lens)
 
-            fn = functools.partial(fn, chain_params(steps))
+            fn = functools.partial(chain_prefill, chain_params(steps))
             self._chain_prefill_fns[sig] = fn
         return fn
 
@@ -231,17 +234,20 @@ class BlockExecutor:
         lens = jnp.asarray([s.prompt_len for s in states], jnp.int32)
         fn = self.chain_prefill_fn(states[0].steps, sig)
         nxt, probs, kvs = fn(jnp.asarray(tok), lens)
-        hop = 0
-        for i, (block, _) in enumerate(states[0].steps):
-            if not block.has_kv:
-                continue
-            _, pool = kv.pool_for(block)
-            k_r, v = kvs[hop]
-            for bi, s in enumerate(states):
-                pool.write_prefill(s.rid, i, k_r[bi:bi + 1, :s.prompt_len],
-                                   v[bi:bi + 1, :s.prompt_len])
-            hop += 1
-        nxt_h, probs_h = jax.device_get((nxt, probs))
+        with Tracer.span("kv.write_prefill"):
+            hop = 0
+            for i, (block, _) in enumerate(states[0].steps):
+                if not block.has_kv:
+                    continue
+                _, pool = kv.pool_for(block)
+                k_r, v = kvs[hop]
+                for bi, s in enumerate(states):
+                    pool.write_prefill(s.rid, i,
+                                       k_r[bi:bi + 1, :s.prompt_len],
+                                       v[bi:bi + 1, :s.prompt_len])
+                hop += 1
+        with Tracer.span("exec.sync"):
+            nxt_h, probs_h = jax.device_get((nxt, probs))
         self._c_host_syncs.inc()
         for i, s in enumerate(states):
             s.kv_len = s.prompt_len
@@ -278,12 +284,13 @@ class BlockExecutor:
         pool_keys, pool_index = self._pool_layout(steps)
 
         @functools.partial(jax.jit, donate_argnums=(2, 3))
-        def fn(params, tok, pools_k, pools_v, tables, kv_len):
+        def chain_decode(params, tok, pools_k, pools_v, tables, kv_len):
             return chain_decode_fused(bind_params(steps, params), pool_index,
                                       tok, pools_k, pools_v, tables, kv_len,
                                       attn_impl=impl)
 
-        out = (functools.partial(fn, chain_params(steps)), tuple(pool_keys))
+        out = (functools.partial(chain_decode, chain_params(steps)),
+               tuple(pool_keys))
         self._fused_fns[sig] = out
         return out
 
@@ -305,14 +312,14 @@ class BlockExecutor:
                 "surrogate chain must share the full chain's KV-pool layout")
 
         @functools.partial(jax.jit, donate_argnums=(3, 4))
-        def fn(params, sur_params, tok, pools_k, pools_v, tables, kv_len,
-               budget):
+        def chain_decode_spec(params, sur_params, tok, pools_k, pools_v,
+                              tables, kv_len, budget):
             return chain_decode_spec_fused(
                 bind_params(steps, params), bind_params(sur_steps, sur_params),
                 pool_index, tok, pools_k, pools_v, tables, kv_len, budget,
                 lookahead=lookahead, attn_impl=impl)
 
-        out = (functools.partial(fn, chain_params(steps),
+        out = (functools.partial(chain_decode_spec, chain_params(steps),
                                  chain_params(sur_steps)), tuple(pool_keys))
         self._spec_fns[key] = out
         return out
@@ -347,15 +354,16 @@ class BlockExecutor:
     def _sync_state(self, ds: DecodeState) -> None:
         if not ds.emitted:
             return  # never stepped: host state is still authoritative
-        blocks, nxt, probs = jax.device_get(
-            (tuple(t for t, _ in ds.emitted), ds.next_token, ds.probs))
-        self._c_host_syncs.inc()
-        for i, s in enumerate(ds.states):
-            for t, cnt in zip(blocks, (c for _, c in ds.emitted)):
-                s.tokens.extend(int(tok) for tok in t[i, :cnt[i]])
-            s.next_token = int(nxt[i])
-            s.probs_last = probs[i]
-            s.kv_len = ds.kv_len0[i] + ds.buffered_counts[i]
+        with Tracer.span("exec.sync"):
+            blocks, nxt, probs = jax.device_get(
+                (tuple(t for t, _ in ds.emitted), ds.next_token, ds.probs))
+            self._c_host_syncs.inc()
+            for i, s in enumerate(ds.states):
+                for t, cnt in zip(blocks, (c for _, c in ds.emitted)):
+                    s.tokens.extend(int(tok) for tok in t[i, :cnt[i]])
+                s.next_token = int(nxt[i])
+                s.probs_last = probs[i]
+                s.kv_len = ds.kv_len0[i] + ds.buffered_counts[i]
 
     def _make_state(self, states: List, kv: KVManager) -> DecodeState:
         steps = states[0].steps
@@ -386,7 +394,8 @@ class BlockExecutor:
         rids = tuple(s.rid for s in states)
         ds = self.decode_states.get(rids)
         if ds is None:
-            ds = self._make_state(states, kv)
+            with Tracer.span("exec.make_state"):
+                ds = self._make_state(states, kv)
         fn, pool_keys = self.fused_fn(states[0].steps, ds.sig)
         pools = [kv.pools[k] for k in pool_keys]
         pk = tuple(p.k_pages for p in pools)
@@ -421,7 +430,8 @@ class BlockExecutor:
         rids = tuple(s.rid for s in states)
         ds = self.decode_states.get(rids)
         if ds is None:
-            ds = self._make_state(states, kv)
+            with Tracer.span("exec.make_state"):
+                ds = self._make_state(states, kv)
         fn, pool_keys = self.spec_fn(states[0].steps, sur_steps, ds.sig,
                                      lookahead)
         pools = [kv.pools[k] for k in pool_keys]
@@ -435,9 +445,10 @@ class BlockExecutor:
                               ds.kv_len, budget)
         for p, k_new, v_new in zip(pools, pk, pv):
             p.k_pages, p.v_pages = k_new, v_new
-        cnt_h, acc_h, att_h = (np.asarray(a, np.int64) for a in
-                               jax.device_get((commit_cnt, accepted,
-                                               attempts)))
+        with Tracer.span("exec.sync"):
+            cnt_h, acc_h, att_h = (np.asarray(a, np.int64) for a in
+                                   jax.device_get((commit_cnt, accepted,
+                                                   attempts)))
         self._c_host_syncs.inc()
         ds.emitted.append((commit_tok, cnt_h))
         for i in range(len(states)):

@@ -23,10 +23,23 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 TRASH_PAGE = 0  # reserved scratch page for padded table entries
+
+
+@jax.jit
+def kv_write_prefill(pages, new, idx):
+    """Scatter a prefill's raw K or V ``(1, S, KVH, hd)`` into the pages
+    ``idx`` of a head-major slab ``(num_pages, KVH, page_size, hd)``,
+    padding S up to ``len(idx)`` whole pages.  The slab is not donated, so
+    XLA copies it whole around the scatter."""
+    npages, page = idx.shape[0], pages.shape[2]
+    new = jnp.pad(new[0], ((0, npages * page - new.shape[1]), (0, 0), (0, 0)))
+    new = new.reshape(npages, page, *new.shape[1:]).transpose(0, 2, 1, 3)
+    return pages.at[idx].set(new.astype(pages.dtype))
 
 
 @dataclass
@@ -132,20 +145,16 @@ class KVPool:
 
     def write_prefill(self, rid: int, step: int, k_r, v):
         """Scatter a prefill's raw K/V (1, S, KVH, hd) into the slot's pages."""
-        slot = self.slots[(rid, step)]
-        S = k_r.shape[1]
-        npages = self.pages_needed(S)
-        cap = npages * self.page_size
-        pad = cap - S
-        if pad:
-            k_r = jnp.pad(k_r, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        kp = k_r[0].reshape(npages, self.page_size, *k_r.shape[2:])
-        vp = v[0].reshape(npages, self.page_size, *v.shape[2:])
-        kp, vp = kp.transpose(0, 2, 1, 3), vp.transpose(0, 2, 1, 3)
-        idx = jnp.asarray(slot.pages[:npages], jnp.int32)
-        self.k_pages = self.k_pages.at[idx].set(kp.astype(self.k_pages.dtype))
-        self.v_pages = self.v_pages.at[idx].set(vp.astype(self.v_pages.dtype))
+        pages = self.slots[(rid, step)].pages
+        idx = jnp.asarray(pages[:self.pages_needed(k_r.shape[1])], jnp.int32)
+        # each call makes a whole new slab (not donated): waiting for this
+        # slab's previous write keeps one queued copy per slab, where
+        # back-to-back calls queue copies until HBM is full; the other
+        # slab's write keeps the device busy meanwhile
+        self.k_pages.block_until_ready()
+        self.k_pages = kv_write_prefill(self.k_pages, k_r, idx)
+        self.v_pages.block_until_ready()
+        self.v_pages = kv_write_prefill(self.v_pages, v, idx)
 
 
 # ---------------------------------------------------------------------------
