@@ -342,7 +342,7 @@ def _chain_step_fused(steps, pool_index, tokens, pools_k, pools_v, tables,
             x = apply_block(block, x, adapters=adapters)
         # pin hop boundaries — the hidden state AND the updated slabs:
         # without this XLA fuses across blocks (including a hop's K/V
-        # scatter into the next hop's reads) and the low-precision rounding
+        # write into the next hop's reads) and the low-precision rounding
         # diverges from the per-hop oracle, flipping near-tie argmaxes;
         # dispatch stays a single device call either way
         x, pools_k, pools_v = jax.lax.optimization_barrier(
@@ -359,7 +359,7 @@ def chain_decode_fused(steps, pool_index, tokens, pools_k, pools_v, tables,
     be jitted once per chain signature (DESIGN.md §2).
 
     Runs embedding -> every attention/MLP/adapter hop (paged-KV decode with
-    in-computation single-token K/V scatter) -> lm_head -> greedy argmax +
+    in-computation single-token K/V write) -> lm_head -> greedy argmax +
     softmax, with no Python dispatch between hops.
 
     tokens: (B,) pending token ids; pools_k/pools_v: tuples of page slabs,

@@ -3,7 +3,7 @@
 ``BlockExecutor`` owns everything that touches device compute for the
 real-execution plane: the fused per-chain-signature megastep (one jitted
 call per group per token: embedding -> every attention/MLP/adapter hop
-with paged-KV decode and in-computation K/V scatter -> lm_head ->
+with paged-KV decode and in-computation K/V write -> lm_head ->
 on-device greedy argmax/softmax), device-resident ``DecodeState`` kept
 across steps, batched multi-request prefill, and — as the parity oracle
 and heterogeneous-tail fallback — the jitted per-(block, adapters)
@@ -133,8 +133,8 @@ class BlockExecutor:
                 raise NotImplementedError(
                     "paged decode does not support sliding-window blocks")
 
-            # donate the pool slabs: the update is a one-token scatter, so
-            # XLA can write in place instead of copying the whole pool
+            # donate the pool slabs: each lane's one-token write then
+            # updates them in place instead of copying the whole pool
             @functools.partial(jax.jit, donate_argnums=(2, 3))
             def block_decode(params, x, k_pages, v_pages, tables, kv_len):
                 (b, ads), = bind_params(hop, params)

@@ -146,6 +146,43 @@ def test_fused_interpret_attention_parity(demo):
         np.testing.assert_array_equal(g.tokens, r.tokens)
 
 
+@pytest.mark.parametrize("B", [1, 3])
+def test_megastep_writes_kv_in_place(demo, B):
+    """The compiled megastep of a LoRA chain writes each lane's new K/V
+    token into the donated pool slabs in place: no copy or transpose of a
+    whole slab anywhere in the optimized program, at one lane or several."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.blocks import chain_signature
+    from repro.serving.executor import BlockExecutor
+
+    cfg, zoo = demo
+    steps = [(zoo.blocks[s.block_id], tuple(zoo.blocks[a]
+                                            for a in s.adapter_ids))
+             for s in zoo.chains["app-lora"].steps]
+    fn, _ = BlockExecutor(attn_impl="ref").fused_fn(
+        steps, chain_signature(steps))
+    hops = sum(b.has_kv for b, _ in steps)
+    n = 4
+    shape = (1 + B * n * hops, cfg.num_kv_heads, 16, cfg.resolved_head_dim)
+    # float32: the CPU backend computes a bf16 update in float32, which
+    # converts whole slabs on its own
+    pool = jax.ShapeDtypeStruct(shape, jnp.float32)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    text = fn.func.lower(
+        jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                     fn.args[0]),
+        i32(B), (pool,), (pool,), tuple(i32(B, n) for _ in range(hops)),
+        i32(B)).compile().as_text()
+    slab = re.escape("f32[" + ",".join(map(str, shape)) + "]")
+    assert re.search(slab + r"\{[^}]*\} dynamic-update-slice\(", text)
+    relayouts = re.findall(slab + r"(?:\{[^}]*\})? (?:copy|transpose)\(", text)
+    assert not relayouts, f"{len(relayouts)} whole-slab copies at B={B}"
+
+
 # ---------------------------------------------------------------------------
 # generate(): gen_len=0 regression
 # ---------------------------------------------------------------------------

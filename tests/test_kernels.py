@@ -113,3 +113,31 @@ def test_page_pool_roundtrip():
                            seq_lens)[:, 0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_write_token_lanes_match_scatter(dtype):
+    """Per-lane in-place writes give the slabs the one 2-index scatter
+    gives, away from the trash page: four lanes, two of them on one page at
+    different slots, one padded onto the trash page (page 0)."""
+    from repro.kernels.paged_attention.ops import write_token_to_pages
+
+    KVH, hd, page = 2, 64, 8
+    ks = jax.random.split(jax.random.PRNGKey(6), 4)
+    k_pages = jax.random.normal(ks[0], (6, KVH, page, hd), dtype)
+    v_pages = jax.random.normal(ks[1], (6, KVH, page, hd), dtype)
+    block_tables = jnp.asarray([[1, 2], [1, 3], [4, 5], [0, 0]], jnp.int32)
+    positions = jnp.asarray([3, 5, 9, 0], jnp.int32)  # lane 2: page 5
+    k_new = jax.random.normal(ks[2], (4, KVH, hd), jnp.float32)
+    v_new = jax.random.normal(ks[3], (4, KVH, hd), jnp.float32)
+
+    page_idx = block_tables[jnp.arange(4), positions // page]
+    slot = positions % page
+    want_k = k_pages.at[page_idx, :, slot].set(k_new.astype(dtype))
+    want_v = v_pages.at[page_idx, :, slot].set(v_new.astype(dtype))
+    got_k, got_v = jax.jit(write_token_to_pages)(
+        k_pages, v_pages, block_tables, positions, k_new, v_new)
+    np.testing.assert_array_equal(np.asarray(got_k[1:], np.float32),
+                                  np.asarray(want_k[1:], np.float32))
+    np.testing.assert_array_equal(np.asarray(got_v[1:], np.float32),
+                                  np.asarray(want_v[1:], np.float32))

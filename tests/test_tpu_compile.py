@@ -8,6 +8,7 @@ test worker collects the same tests and only the worker running this file
 loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.paged_attention.kernel import paged_attention
+from repro.kernels.paged_attention.ops import paged_decode_step
 
 
 @pytest.fixture(scope="module")
@@ -93,3 +95,30 @@ def test_demo_megastep_compiles_for_v5e(one_chip):
     assert text.count('custom_call_target="tpu_custom_call"') == hops
     # weights stayed arguments: the program is far smaller than them
     assert len(lowered.as_text()) < zoo.zoo_bytes() // 4
+
+
+def test_multi_lane_kv_write_in_place_for_v5e(one_chip):
+    """Two donated megastep hops at DeepSeek-LLM-7B's pool (385 pages of
+    512 tokens, 32 KV heads of 128, bf16) with 4 lanes and the Pallas
+    kernel: each lane's K/V token is written in place, and no op copies a
+    whole slab between the write and the kernel."""
+    P, KVH, page, hd, B, n = 385, 32, 512, 128, 4, 8
+
+    def two_hops(q, k_new, v_new, k_pages, v_pages, tables, kv_len):
+        for _ in range(2):
+            q, k_pages, v_pages = paged_decode_step(
+                q, k_new, v_new, k_pages, v_pages, tables, kv_len,
+                impl="pallas")
+            q, k_pages, v_pages = jax.lax.optimization_barrier(
+                (q, k_pages, v_pages))
+        return q, k_pages, v_pages
+
+    tok = _sds((B, KVH, hd), jnp.bfloat16, one_chip)
+    pages = _sds((P, KVH, page, hd), jnp.bfloat16, one_chip)
+    text = jax.jit(two_hops, donate_argnums=(3, 4)).lower(
+        tok, tok, tok, pages, pages, _sds((B, n), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    slab = re.escape(f"bf16[{P},{KVH},{page},{hd}]")
+    copies = re.findall(slab + r"(?:\{[^}]*\})? (?:copy|transpose)\(", text)
+    assert not copies, f"{len(copies)} whole-slab copies"
