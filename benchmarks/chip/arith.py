@@ -1,5 +1,6 @@
 """The benchmark's own arithmetic: device peaks, percentiles, latency
-definitions and the operation and byte counts of the served model.
+definitions, rooflines, and the definitions of model FLOPs that the
+architecture modules (``archs/``) fill with their own counts.
 
 Nothing here imports the program: a later change to the program cannot
 move the yardstick."""
@@ -80,50 +81,26 @@ def tokens_in_window(rec: dict, w0: float, w1: float) -> float:
 # -- the model's work ---------------------------------------------------------
 
 
-def layer_params(cfg: dict) -> int:
-    """Parameters of one llama layer (RMSNorm, GQA attention, SwiGLU)."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, kvh, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  cfg["head_dim"])
-    return 2 * d + d * hd * (2 * h + 2 * kvh) + 3 * d * f
-
-
-def attn_flops(cfg: dict, context: int) -> float:
-    """Score and value products of one query token over ``context``
-    cached tokens, in one attention layer."""
-    return 4.0 * cfg["num_attention_heads"] * cfg["head_dim"] * context
-
-
-def decode_attn_bytes(cfg: dict, context: int, dtype_bytes: int = 2) -> float:
-    """Bytes one query token's paged attention has to move in one layer:
-    the live K and V of ``context`` tokens, plus q read and out written."""
-    kv = 2 * cfg["num_key_value_heads"] * cfg["head_dim"] * context
-    qo = 2 * cfg["num_attention_heads"] * cfg["head_dim"]
-    return float((kv + qo) * dtype_bytes)
-
-
 def roofline_s(flops: float, nbytes: float, pk: Dict[str, float]):
     """(least time, bound) for ``flops`` and ``nbytes`` on a chip."""
     tc, tm = flops / pk["bf16_flops"], nbytes / pk["hbm_bytes_s"]
     return (tc, "compute") if tc >= tm else (tm, "memory")
 
 
-def decode_token_flops(cfg: dict, chain_matmul_params: int,
-                       contexts: Iterable[int]) -> float:
+def decode_token_flops(chain_matmul_params: int,
+                       attn_flops: Iterable[float]) -> float:
     """Model FLOPs of one decoded token: 2 x the matmul parameters of its
     chain plus attention over its context in each attention layer
-    (``contexts`` lists the context per attention layer)."""
-    return 2.0 * chain_matmul_params + sum(attn_flops(cfg, c)
-                                           for c in contexts)
+    (``attn_flops`` lists the attention FLOPs per attention layer)."""
+    return 2.0 * chain_matmul_params + sum(attn_flops)
 
 
-def prefill_flops(cfg: dict, chain_matmul_params: int, head_params: int,
-                  prompt_len: int, n_attn: int) -> float:
+def prefill_flops(chain_matmul_params: int, head_params: int,
+                  prompt_len: int, n_attn: int, pair_flops: float) -> float:
     """Model FLOPs of one prompt: every token through the chain's layers,
-    causal attention over the prompt in each attention layer, and the
-    head once, for the last position."""
+    causal attention over the prompt in each attention layer (``pair_flops``
+    for each query and key pair), and the head once, for the last
+    position."""
     body = 2.0 * (chain_matmul_params - head_params) * prompt_len
-    attn = n_attn * 4.0 * cfg["num_attention_heads"] * cfg["head_dim"] * (
-        prompt_len * (prompt_len + 1) / 2)
+    attn = n_attn * pair_flops * (prompt_len * (prompt_len + 1) / 2)
     return body + attn + 2.0 * head_params
-

@@ -38,7 +38,7 @@ def read_seed(cell, seed: int, seconds: float, attn_impl: str = "pallas",
     del engine
     picked = sample(cell, run, seed)
     t0 = time.perf_counter()
-    got = reference.check(cell.cfg, weights, picked, control=True, log=log)
+    got = reference.check(cell, weights, picked, control=True, log=log)
     got = got or {"tokens": 0, "served": None, "control": None}
     correct, _ = judge(cell, run, got["served"])
     control_correct, compared = judge(cell, run, got["control"])

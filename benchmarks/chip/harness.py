@@ -13,12 +13,13 @@ import json
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import jax
 import numpy as np
 
-from benchmarks.chip import model, traffic
+from benchmarks.chip import archs, model, traffic
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent.parent
@@ -26,10 +27,17 @@ ROOT = HERE.parent.parent
 
 @dataclass
 class Cell:
+    """A configuration under a traffic mix, with the configuration's
+    architecture module (by default ``archs/<model_type>.py``)."""
     name: str
     cfg: dict
     mix: dict
     chips: int = 1
+    arch: Optional[ModuleType] = None
+
+    def __post_init__(self):
+        if self.arch is None:
+            self.arch = archs.load(self.cfg["model_type"])
 
 
 def load_cell(name: str, bench_path=ROOT / "BENCHMARK.json",
@@ -40,7 +48,8 @@ def load_cell(name: str, bench_path=ROOT / "BENCHMARK.json",
         raise KeyError(f"no workload {name!r} in {bench_path}")
     cfg = model.load_config(w["config"], here / "configs")
     mix = traffic.load_mix(here / "traffic" / f"{w['traffic']}.json")
-    return Cell(name, cfg, mix, w["chips"])
+    arch = archs.load(cfg["model_type"], here / "archs")
+    return Cell(name, cfg, mix, w["chips"], arch)
 
 
 @dataclass
@@ -110,10 +119,10 @@ class _CompileCounter:
 def build(cell: Cell, seed: int, attn_impl: str, log=print):
     """Weights, zoo and engine for a cell."""
     t0 = time.perf_counter()
-    weights = model.make_weights(cell.cfg, seed)
+    weights = cell.arch.make_weights(cell.cfg, seed)
     jax.block_until_ready(weights)
     t1 = time.perf_counter()
-    zoo = model.build_zoo(cell.cfg, weights, log)
+    zoo = cell.arch.build_zoo(cell.cfg, weights, log)
     log(f"weights {t1 - t0:.1f} s, zoo {time.perf_counter() - t1:.1f} s")
     return weights, zoo, make_engine(cell, zoo, attn_impl)
 

@@ -6,8 +6,10 @@ a lane with ``kv`` cached tokens attends over kv + 1 and moves their
 live K and V plus q and out, in every attention hop.  Pages the kernel
 happens to read beyond that do not count.  Denominator: the summed
 device time of the kernel's events.  Decode attention is memory bound at
-these shapes (1 to 4 FLOPs a byte against the chip's 240)."""
-from benchmarks.chip.arith import attn_flops, decode_attn_bytes, roofline_s
+these shapes (1 to 4 FLOPs a byte against the chip's 240).  The FLOPs
+and bytes of one hop come from the configuration's architecture module
+(``decode_attn_work`` in ``archs/<model_type>.py``)."""
+from benchmarks.chip.arith import roofline_s
 
 
 def read(run):
@@ -16,13 +18,14 @@ def read(run):
     if not run.trace or not run.trace["kernel_s"].get("paged_attention"):
         return None
     a, b = run.trace["host_window"]
-    cfg = run.cell.cfg
+    cfg, arch = run.cell.cfg, run.cell.arch
     flops = nbytes = 0.0
     for s in run.steps:
         if s["t0"] >= a and s["t1"] <= b:
             for _, kv, hops in s["lanes"]:
-                flops += hops * attn_flops(cfg, kv + 1)
-                nbytes += hops * decode_attn_bytes(cfg, kv + 1)
+                f, n = arch.decode_attn_work(cfg, kv + 1)
+                flops += hops * f
+                nbytes += hops * n
     if not flops:
         return None
     t, _ = roofline_s(flops, nbytes, run.peaks)

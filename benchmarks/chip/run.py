@@ -102,7 +102,7 @@ def measure(cell, *, seed: int, seconds: float, trace: bool, bench: dict,
     del engine
 
     t0 = time.perf_counter()
-    got = reference.check(cell.cfg, weights, sample(cell, run, seed), log=log)
+    got = reference.check(cell, weights, sample(cell, run, seed), log=log)
     log(f"reference in {time.perf_counter() - t0:.1f} s")
     correct, compared = judge(cell, run, got["served"] if got else None)
     key = "per_layer" if trace else "end_to_end"

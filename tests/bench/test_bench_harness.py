@@ -4,8 +4,10 @@ The run loop is driven end to end with the Pallas kernel in interpret
 mode; the chip check is skipped by calling ``measure`` directly."""
 from __future__ import annotations
 
+import dataclasses
 import json
 import math
+import re
 import sys
 from pathlib import Path
 
@@ -15,12 +17,17 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
 
-from benchmarks.chip import arith, control, harness, model, traffic  # noqa: E402
+from benchmarks.chip import arith, control, harness, model, reference  # noqa: E402
 from benchmarks.chip import run as bench_run  # noqa: E402
+from benchmarks.chip import traffic  # noqa: E402
+from benchmarks.chip.archs import llama  # noqa: E402
 from benchmarks.chip.metrics import reader  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 SEED = 2 ** 40 + 3  # wider than 32 bits: seeds may be
+TINY = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
+            vocab_size=256)
 
 
 OPEN_MIX = {"loop": "open", "rate_rps": 4.0, "apps": {"pick": "zipf", "s": 1.0},
@@ -35,9 +42,7 @@ def tiny_cell() -> harness.Cell:
     """Mistral's configuration file at test widths, with one tenant of
     every kind, under an open-loop mix at test lengths."""
     cfg = model.load_config("mistral-7b-v0.3-l8")
-    cfg.update(hidden_size=64, intermediate_size=128, num_attention_heads=4,
-               num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
-               vocab_size=256)
+    cfg.update(TINY)
     keep = ("base", "lora-0", "adapter-0", "bitfit-0", "fpft-0")
     cfg["tenants"] = [t for t in cfg["tenants"] if t["name"] in keep]
     for t in cfg["tenants"]:
@@ -157,8 +162,6 @@ def test_the_float8_control_is_not_correct():
 
 
 def test_the_sample_holds_the_longest_request_and_every_tenant_kind():
-    from benchmarks.chip import reference
-
     cfg = model.load_config("mistral-7b-v0.3-l8")
     kinds = {t["name"]: t["kind"] for t in cfg["tenants"]}
     done = [{"rid": i, "app": a, "prompt_len": 100 + i, "n_out": 64}
@@ -173,28 +176,97 @@ def test_the_sample_holds_the_longest_request_and_every_tenant_kind():
 # -- found by name ------------------------------------------------------------
 
 
+# the llama layer under width keys of its own: only its module reads them
+TOY_KEYS = {"width": "hidden_size", "ffn_width": "intermediate_size",
+            "heads": "num_attention_heads", "kv_groups": "num_key_value_heads",
+            "head_width": "head_dim", "depth": "num_hidden_layers"}
+TOY_ARCH = f"""from benchmarks.chip.archs import llama
+
+KEYS = {TOY_KEYS!r}
+
+
+def _llama(cfg):
+    return {{KEYS.get(k, k): v for k, v in cfg.items()}}
+
+
+def make_weights(cfg, seed):
+    return llama.make_weights(_llama(cfg), seed)
+
+
+def build_zoo(cfg, weights, log=None):
+    return llama.build_zoo(_llama(cfg), weights, log)
+
+
+def logits(cfg, weights, app, seq, positions, low=False):
+    return llama.logits(_llama(cfg), weights, app, seq, positions, low)
+
+
+def decode_token_flops(cfg, app, contexts):
+    return llama.decode_token_flops(_llama(cfg), app, contexts)
+
+
+def prefill_flops(cfg, app, prompt_len):
+    return llama.prefill_flops(_llama(cfg), app, prompt_len)
+
+
+def decode_attn_work(cfg, context):
+    return llama.decode_attn_work(_llama(cfg), context)
+"""
+
+
 def test_a_new_config_mix_and_metric_are_found_by_name(tmp_path):
+    """A configuration of a new ``model_type`` is one more file under
+    ``archs/``: no llama key is read outside it, from ``load_cell``
+    through ``build``, the run loop, the reference check and ``step.mfu``."""
     here = tmp_path / "chip"
-    for d in ("configs", "traffic", "metrics"):
+    for d in ("configs", "traffic", "metrics", "archs"):
         (here / d).mkdir(parents=True)
-    cfg = model.load_config("deepseek-llm-7b-l6")
+    tiny = tiny_cell().cfg
+    to_toy = {v: k for k, v in TOY_KEYS.items()}
+    cfg = {to_toy.get(k, k): v for k, v in tiny.items()}
+    cfg["model_type"] = "toy"
     (here / "configs/new-model.json").write_text(json.dumps(cfg))
+    (here / "configs/unknown.json").write_text(json.dumps(
+        dict(cfg, model_type="nowhere")))
+    (here / "archs/toy.py").write_text(TOY_ARCH)
     (here / "traffic/new-mix.json").write_text(json.dumps(
         {"loop": "open", "rate_rps": 1.0, "apps": {"pick": "even"},
          "prompt": {"dist": "uniform", "min": 8, "max": 16},
          "output": {"dist": "uniform", "min": 2, "max": 4}}))
     (here / "metrics/new.metric.py").write_text(
         "def read(run):\n    return run.seconds * 2\n")
-    bench = dict(BENCH, workloads=[{"name": "new-model.new-mix",
-                                    "config": "new-model",
-                                    "traffic": "new-mix", "chips": 1,
-                                    "why": "x"}])
+    bench = dict(BENCH, workloads=[
+        {"name": f"{c}.new-mix", "config": c, "traffic": "new-mix",
+         "chips": 1, "why": "x"} for c in ("new-model", "unknown")])
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     cell = harness.load_cell("new-model.new-mix", tmp_path / "BENCHMARK.json",
                              here=here)
     assert cell.cfg["name"] == "new-model" and cell.mix["rate_rps"] == 1.0
+    assert Path(cell.arch.__file__) == (here / "archs/toy.py").resolve()
+    assert not set(TOY_KEYS.values()) & set(cell.cfg)
     run = harness.RunData(cell=cell, seconds=3.0)
     assert reader("new.metric", here / "metrics")(run) == 6.0
+    with pytest.raises(KeyError, match=re.escape(str(here / "archs/nowhere.py"))):
+        harness.load_cell("unknown.new-mix", tmp_path / "BENCHMARK.json",
+                          here=here)
+
+    quiet = lambda m: None  # noqa: E731
+    weights, _, engine = harness.build(cell, SEED, "ref", log=quiet)
+    drv = harness.Driver(engine, cell, SEED, run, log=quiet)
+    drv.sample_steps = True
+    t0 = drv.clock()
+    drv.schedule("window", t0, run.seconds)
+    drv.loop(lambda now: now >= t0 + 120 or (
+        now >= t0 + run.seconds and len(run.done) == run.attempted))
+    harness.free(engine)
+    run.w0, run.w1 = t0, t0 + run.seconds
+    assert len(run.done) == run.attempted == 3
+    got = reference.check(cell, weights, run.done, log=quiet)
+    assert got["served"] <= cfg["check"]["widest_logit_gap"]
+    run.peaks = arith.peaks("TPU v5 lite")
+    twin = dataclasses.replace(run, cell=harness.Cell("twin", tiny, cell.mix))
+    mfu = reader("step.mfu")(run)
+    assert mfu > 0 and mfu == reader("step.mfu")(twin)
 
 
 def test_every_named_metric_and_file_exists():
@@ -230,12 +302,12 @@ def test_ttft_tpot_and_tokens_in_window_by_hand():
 def test_flops_bytes_and_roofline_by_hand():
     mistral = model.load_config("mistral-7b-v0.3-l8")
     deepseek = model.load_config("deepseek-llm-7b-l6")
-    assert arith.layer_params(mistral) == 218_112_000
-    assert arith.layer_params(deepseek) == 202_383_360
+    assert llama.layer_params(mistral) == 218_112_000
+    assert llama.layer_params(deepseek) == 202_383_360
     # one token over 1000 cached: 4 * 32 heads * 128 * 1000
-    assert arith.attn_flops(mistral, 1000) == 16_384_000
+    assert llama.attn_flops(mistral, 1000) == 16_384_000
     # K and V of 8 heads x 128 x 1000 tokens plus q and out, in bf16
-    assert arith.decode_attn_bytes(mistral, 1000) == (
+    assert llama.decode_attn_bytes(mistral, 1000) == (
         2 * 8 * 128 * 1000 + 2 * 32 * 128) * 2
     pk = arith.peaks("TPU v5 lite")
     t, bound = arith.roofline_s(16_384_000, 4_112_384, pk)
@@ -245,8 +317,18 @@ def test_flops_bytes_and_roofline_by_hand():
     # a prompt of 2 tokens: body 2 x 2 x (P - head), causal attention over
     # 1 + 2 positions in each layer, the head once
     P, head = 1000, 100
-    got = arith.prefill_flops(mistral, P, head, 2, 8)
+    got = arith.prefill_flops(P, head, 2, 8, llama.attn_flops(mistral, 1))
     assert got == 2 * 900 * 2 + 8 * 4 * 32 * 128 * 3 + 2 * 100
+    # the module's own counts: the same definitions over a tenant's chain
+    base = {"name": "base", "kind": "foundation"}
+    P = llama.chain_matmul_params(mistral, base)
+    assert P == 8 * (218_112_000 - 2 * 4096) + 4096 * 32768
+    assert llama.prefill_flops(mistral, "base", 2) == arith.prefill_flops(
+        P, 4096 * 32768, 2, 8, 16_384)
+    assert llama.decode_token_flops(mistral, "base", [1000] * 8) == (
+        2 * P + 8 * 16_384_000)
+    assert llama.decode_attn_work(mistral, 1000) == (
+        16_384_000, (2 * 8 * 128 * 1000 + 2 * 32 * 128) * 2)
 
 
 def test_traffic_gives_every_seed_the_same_sizes_in_another_order():
