@@ -48,6 +48,16 @@ class ModelConfig:
     num_experts_per_tok: int = 0
     moe_impl: str = "dense"  # "dense" (scan all experts) | "dispatch" (capacity EP)
     capacity_factor: float = 1.25
+    routed_scaling_factor: float = 1.0  # DeepSeekMoE: routed output scale
+
+    # --- multi-head latent attention (DeepSeek-V2); 0 = not MLA ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN: the HF ``rope_scaling`` items (factor, beta_fast, beta_slow,
+    # mscale, mscale_all_dim, original_max_position_embeddings)
+    rope_scaling: Tuple[Tuple[str, float], ...] = ()
 
     # --- attention flavour ---
     sliding_window: int = 0  # 0 = full causal attention

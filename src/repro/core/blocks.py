@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.models import deepseek_v2 as DS
 from repro.models import layers as L
 from repro.models.transformer import _mlp_layer
 
@@ -36,6 +37,16 @@ def tree_hash(tree) -> str:
 
 
 ATTENTION_KINDS = ("layer", "attention")  # block kinds that own KV state
+
+
+def is_mla(params) -> bool:
+    """Multi-head latent attention (DeepSeek-V2) params."""
+    return "kv_b_proj" in params
+
+
+def is_moe(params) -> bool:
+    """DeepSeekMoE feed-forward params (router, routed and shared experts)."""
+    return "router" in params
 
 
 @dataclass
@@ -60,10 +71,13 @@ class Block:
         return self.kind in ATTENTION_KINDS
 
     @property
-    def kv_signature(self) -> Tuple[int, int]:
+    def kv_signature(self) -> Tuple:
         """(kv_heads, head_dim) — the KV-pool signature this block's slots
-        live under (one shared pool per signature, DESIGN.md §2)."""
+        live under (one shared pool per signature, DESIGN.md §2); an MLA
+        block's is ("latent", row width): one row a token, no V."""
         cfg = self.cfg
+        if is_mla(self.params):
+            return ("latent", cfg.kv_lora_rank + cfg.qk_rope_head_dim)
         return (cfg.num_kv_heads or cfg.num_heads, cfg.resolved_head_dim)
 
     @property
@@ -71,8 +85,16 @@ class Block:
         return tree_bytes(self.params)
 
     def flops_per_token(self) -> float:
-        """2 * params is the dense-matmul flops estimate per token."""
-        return 2.0 * self.n_params
+        """2 * the params a token passes through: all of them, but of an
+        expert layer's routed experts only the ``num_experts_per_tok`` it
+        is sent to."""
+        n = self.n_params
+        if is_moe(self.params):
+            routed = sum(self.params[w].size
+                         for w in ("e_gate", "e_up", "e_down"))
+            E = self.params["e_gate"].shape[0]
+            n -= routed * (E - self.cfg.num_experts_per_tok) // E
+        return 2.0 * n
 
 
 # ---------------------------------------------------------------------------
@@ -81,6 +103,8 @@ class Block:
 
 
 def _attn_sublayer(x, p, cfg, positions, adapters=()):
+    if is_mla(p):
+        return DS.mla_prefill(x, p, cfg, positions, adapters)[0]
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     wq, wk, wv = p["wq"], p["wk"], p["wv"]
     q = jnp.einsum("bsd,dhk->bshk", h, wq.astype(h.dtype))
@@ -108,8 +132,12 @@ def _attn_sublayer(x, p, cfg, positions, adapters=()):
     return x + o
 
 
-def _ffn_sublayer(x, p, cfg, adapters=()):
-    out = _mlp_layer(x, p, cfg, None)
+def _ffn_sublayer(x, p, cfg, adapters=(), impl="auto", stats=None):
+    if is_moe(p):
+        out = x + DS.moe_ffn(L.rms_norm(x, p["ln2"], cfg.norm_eps), p, cfg,
+                             impl=impl, stats=stats)
+    else:
+        out = _mlp_layer(x, p, cfg, None)
     for a in adapters:
         if a.kind == "adapter":
             ap = a.params
@@ -119,8 +147,11 @@ def _ffn_sublayer(x, p, cfg, adapters=()):
     return out
 
 
-def apply_block(block: Block, x, *, positions=None, adapters=()):
-    """x: hidden states (B, S, D) — or token ids for embed blocks."""
+def apply_block(block: Block, x, *, positions=None, adapters=(),
+                impl: str = "auto", stats=None):
+    """x: hidden states (B, S, D) — or token ids for embed blocks.
+    ``impl`` picks the expert FFN's grouped matmul (as ``attn_impl`` picks
+    the paged kernel); ``stats`` collects its routing counts."""
     cfg = block.cfg
     p = block.params
     if block.kind == "embed":
@@ -134,7 +165,7 @@ def apply_block(block: Block, x, *, positions=None, adapters=()):
             positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
         x0 = x
         x = _attn_sublayer(x, p, cfg, positions, adapters)
-        out = _ffn_sublayer(x, p, cfg, adapters)
+        out = _ffn_sublayer(x, p, cfg, adapters, impl, stats)
         if "recover_a" in p:  # surrogate LoRA recovery (paper §5.2)
             out = out + jnp.einsum(
                 "bsd,dr,re->bse", x0, p["recover_a"].astype(x0.dtype),
@@ -146,7 +177,7 @@ def apply_block(block: Block, x, *, positions=None, adapters=()):
             positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
         return _attn_sublayer(x, p, cfg, positions, adapters)
     if block.kind == "ffn":
-        return _ffn_sublayer(x, p, cfg, adapters)
+        return _ffn_sublayer(x, p, cfg, adapters, impl, stats)
     if block.kind == "stitch":
         B, S, D = x.shape
         posval = jnp.full((B, S, 1), float(block.meta["position_value"]),
@@ -161,20 +192,28 @@ def apply_block(block: Block, x, *, positions=None, adapters=()):
 # ---------------------------------------------------------------------------
 
 
-def block_prefill_raw(block: Block, x, *, positions=None, adapters=()):
+def block_prefill_raw(block: Block, x, *, positions=None, adapters=(),
+                      impl: str = "auto"):
     """Prefill one block, returning the raw rotated K and V alongside the
     output (``(out, k_r, v)``; ``k_r``/``v`` are ``None`` for blocks without
-    attention state).  The paged serving engine scatters the raw K/V into its
+    attention state; an MLA block returns its latent rows as ``k_r`` and no
+    ``v``).  The paged serving engine scatters the raw K/V into its
     shared page pool; ``block_prefill`` wraps this with the dense ring-buffer
     cache layout instead."""
     cfg = block.cfg
     p = block.params
     if block.kind not in ("layer", "attention"):
-        out = apply_block(block, x, positions=positions, adapters=adapters)
+        out = apply_block(block, x, positions=positions, adapters=adapters,
+                          impl=impl)
         return out, None, None
     B, S = x.shape[:2]
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+    if is_mla(p):
+        out, lat = DS.mla_prefill(x, p, cfg, positions, adapters)
+        if block.kind == "layer":
+            out = _ffn_sublayer(out, p, cfg, adapters, impl)
+        return out, lat, None
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     q = jnp.einsum("bsd,dhk->bshk", h, p["wq"].astype(h.dtype))
     k = jnp.einsum("bsd,dhk->bshk", h, p["wk"].astype(h.dtype))
@@ -187,7 +226,7 @@ def block_prefill_raw(block: Block, x, *, positions=None, adapters=()):
     o = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(h.dtype))
     out = x + o
     if block.kind == "layer":
-        out = _ffn_sublayer(out, p, cfg, adapters)
+        out = _ffn_sublayer(out, p, cfg, adapters, impl)
     return out, k_r, v
 
 
@@ -232,12 +271,14 @@ def block_decode(block: Block, x, cache, kv_len, *, adapters=()):
 
 
 def block_decode_paged(block: Block, x, k_pages, v_pages, block_tables,
-                       kv_len, *, adapters=(), attn_impl: str = "auto"):
+                       kv_len, *, adapters=(), attn_impl: str = "auto",
+                       stats=None):
     """One-token step over a shared paged KV pool (DESIGN.md §2).
 
     x: (B, 1, D) hidden states (or token ids for embed blocks);
-    k_pages/v_pages: (P, KVH, page_size, hd) pool slabs; block_tables:
-    (B, n) page ids per sequence; kv_len: (B,) tokens already cached.
+    k_pages/v_pages: (P, KVH, page_size, hd) pool slabs (an MLA block's
+    latent slab and ``None``); block_tables: (B, n) page ids per sequence;
+    kv_len: (B,) tokens already cached.
 
     Writes the new token's K/V into the pool and attends over the pages via
     the paged-attention kernel (Pallas on TPU, jnp oracle elsewhere).
@@ -246,7 +287,15 @@ def block_decode_paged(block: Block, x, k_pages, v_pages, block_tables,
     cfg = block.cfg
     p = block.params
     if block.kind not in ("layer", "attention"):
-        return (apply_block(block, x, adapters=adapters), k_pages, v_pages)
+        return (apply_block(block, x, adapters=adapters, impl=attn_impl,
+                            stats=stats), k_pages, v_pages)
+    if is_mla(p):
+        out, k_pages = DS.mla_decode_paged(x, p, cfg, k_pages, block_tables,
+                                           kv_len, adapters=adapters,
+                                           attn_impl=attn_impl)
+        if block.kind == "layer":
+            out = _ffn_sublayer(out, p, cfg, adapters, attn_impl, stats)
+        return out, k_pages, v_pages
     from repro.kernels.paged_attention.ops import paged_decode_step
 
     positions = kv_len[:, None]
@@ -264,7 +313,7 @@ def block_decode_paged(block: Block, x, k_pages, v_pages, block_tables,
                    p["wo"].astype(x.dtype))[:, None]
     out = x + o
     if block.kind == "layer":
-        out = _ffn_sublayer(out, p, cfg, adapters)
+        out = _ffn_sublayer(out, p, cfg, adapters, attn_impl, stats)
     return out, k_pages, v_pages
 
 
@@ -320,14 +369,15 @@ def bind_params(steps, params):
 
 
 def _chain_step_fused(steps, pool_index, tokens, pools_k, pools_v, tables,
-                      kv_len, attn_impl: str):
+                      kv_len, attn_impl: str, stats=None):
     """One single-token walk of a whole chain over the paged pools — the
     shared body of ``chain_decode_fused`` and of every draft/verify
     sub-step inside ``chain_decode_spec_fused``.  The speculative verify
     pass reuses THIS exact computation (same ops, same barriers) so its
     token stream is bitwise identical to the plain fused path.
 
-    pools_k/pools_v are lists and are threaded through; returns
+    pools_k/pools_v are lists and are threaded through; ``stats`` (a
+    list) collects each expert layer's routing counts; returns
     (next_tokens, probs, pools_k, pools_v)."""
     x = tokens[:, None]  # (B, 1) ids; the embed hop maps them to hidden
     hop = 0
@@ -336,10 +386,11 @@ def _chain_step_fused(steps, pool_index, tokens, pools_k, pools_v, tables,
             pi = pool_index[hop]
             x, pools_k[pi], pools_v[pi] = block_decode_paged(
                 block, x, pools_k[pi], pools_v[pi], tables[hop], kv_len,
-                adapters=adapters, attn_impl=attn_impl)
+                adapters=adapters, attn_impl=attn_impl, stats=stats)
             hop += 1
         else:
-            x = apply_block(block, x, adapters=adapters)
+            x = apply_block(block, x, adapters=adapters, impl=attn_impl,
+                            stats=stats)
         # pin hop boundaries — the hidden state AND the updated slabs:
         # without this XLA fuses across blocks (including a hop's K/V
         # write into the next hop's reads) and the low-precision rounding
@@ -354,7 +405,7 @@ def _chain_step_fused(steps, pool_index, tokens, pools_k, pools_v, tables,
 
 
 def chain_decode_fused(steps, pool_index, tokens, pools_k, pools_v, tables,
-                       kv_len, *, attn_impl: str = "auto"):
+                       kv_len, moe_acc=None, *, attn_impl: str = "auto"):
     """One full-chain decode megastep for a batch of sequences, designed to
     be jitted once per chain signature (DESIGN.md §2).
 
@@ -367,13 +418,22 @@ def chain_decode_fused(steps, pool_index, tokens, pools_k, pools_v, tables,
     the i-th attention hop uses; tables: tuple of (B, n) page tables, one
     per attention hop; kv_len: (B,) tokens already cached.
 
-    Returns (next_tokens, probs, pools_k, pools_v, kv_len + 1).
+    Returns (next_tokens, probs, pools_k, pools_v, kv_len + 1), and for a
+    chain with expert layers, given the (3,) int32 ``moe_acc``, that plus
+    this step's (token-expert pairs, experts hit, expert-layer calls).
     """
     pools_k, pools_v = list(pools_k), list(pools_v)
+    stats = None if moe_acc is None else []
     next_tokens, probs, pools_k, pools_v = _chain_step_fused(
         steps, pool_index, tokens, pools_k, pools_v, tables, kv_len,
-        attn_impl)
-    return next_tokens, probs, tuple(pools_k), tuple(pools_v), kv_len + 1
+        attn_impl, stats)
+    out = (next_tokens, probs, tuple(pools_k), tuple(pools_v), kv_len + 1)
+    if moe_acc is None:
+        return out
+    step = jnp.stack([jnp.asarray(sum(n for n, _ in stats), jnp.int32),
+                      sum(h for _, h in stats).astype(jnp.int32),
+                      jnp.asarray(len(stats), jnp.int32)])
+    return out + (moe_acc + step,)
 
 
 def chain_decode_spec_fused(steps, sur_steps, pool_index, tokens, pools_k,
@@ -458,7 +518,7 @@ def chain_decode_spec_fused(steps, sur_steps, pool_index, tokens, pools_k,
             probs_out, tuple(pools_k), tuple(pools_v), kv_len + commit_cnt)
 
 
-def chain_prefill_fused(steps, tokens, lens):
+def chain_prefill_fused(steps, tokens, lens, impl: str = "auto"):
     """Batched multi-request prefill through a whole chain (one jitted call
     per (chain signature, length bucket) instead of one per request).
 
@@ -467,14 +527,15 @@ def chain_prefill_fused(steps, tokens, lens):
     position, so per-row results match the unpadded single-request path.
 
     Returns (next_tokens, probs, kvs) where kvs[i] = (k_r, v) raw rotated
-    K/V (B, S, KVH, hd) for the i-th attention hop.
+    K/V (B, S, KVH, hd) for the i-th attention hop, or (latent,) for an
+    MLA hop.
     """
     x = tokens
     kvs = []
     for block, adapters in steps:
-        x, k_r, v = block_prefill_raw(block, x, adapters=adapters)
+        x, k_r, v = block_prefill_raw(block, x, adapters=adapters, impl=impl)
         if k_r is not None:
-            kvs.append((k_r, v))
+            kvs.append(tuple(t for t in (k_r, v) if t is not None))
         # pin hop boundaries, exactly as in chain_decode_fused: the KV this
         # writes seeds every later decode step, and a 1-ulp rounding
         # difference from cross-block fusion flips near-tie argmaxes

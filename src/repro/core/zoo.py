@@ -25,6 +25,14 @@ from repro.configs.base import ModelConfig
 from repro.core.blocks import Block, BlockChain, ChainStep, tree_bytes, tree_hash
 from repro.core.equivalence import param_equivalence
 
+# a layer block's params by sublayer: the llama layer (with its own qkv
+# biases), multi-head latent attention, the dense and the DeepSeekMoE FFN
+ATTENTION_KEYS = ("ln1", "wq", "wk", "wv", "wo", "bq", "bk", "bv",
+                  "q_proj", "kv_a_proj_with_mqa", "kv_a_layernorm",
+                  "kv_b_proj", "o_proj")
+FFN_KEYS = ("ln2", "w_gate", "w_up", "w_down", "router", "e_gate", "e_up",
+            "e_down", "shared_gate", "shared_up", "shared_down")
+
 DEDUP_THRESHOLD = 0.995   # parametric: treat as the same block
 EQUIV_THRESHOLD = 0.98    # paper §7.1: adaptive-serving equivalence
 
@@ -164,8 +172,11 @@ class BlockZoo:
         if "split" in blk.meta:
             return blk.meta["split"]
         p = blk.params
-        att_p = {k: p[k] for k in ("ln1", "wq", "wk", "wv", "wo") if k in p}
-        ffn_p = {k: p[k] for k in ("ln2", "w_gate", "w_up", "w_down") if k in p}
+        att_p = {k: p[k] for k in ATTENTION_KEYS if k in p}
+        ffn_p = {k: p[k] for k in FFN_KEYS if k in p}
+        lost = set(p) - set(att_p) - set(ffn_p)
+        if lost:
+            raise ValueError(f"split of {layer_id} would drop {sorted(lost)}")
         att = self._make_block("attention", blk.model, blk.layer_idx,
                                blk.d_in, blk.d_out, att_p, blk.cfg)
         ffn = self._make_block("ffn", blk.model, blk.layer_idx,

@@ -54,15 +54,17 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim // 2, dtype=jnp.float32) * 2.0 / head_dim))
 
 
-def apply_rope(x, positions, theta: float, mrope_sections: Tuple[int, ...] = ()):
+def apply_rope(x, positions, theta: float, mrope_sections: Tuple[int, ...] = (),
+               inv_freq=None):
     """x: (B, S, H, hd).  positions: (B, S) int32 or (B, S, 3) for M-RoPE.
 
     M-RoPE (qwen2-vl): the head_dim/2 frequency slots are split into
     sections; each section takes its angle from a different position stream
-    (temporal / height / width).
+    (temporal / height / width).  ``inv_freq`` (hd/2,) replaces the plain
+    ``theta`` frequencies (YaRN).
     """
     hd = x.shape[-1]
-    inv = rope_freqs(hd, theta)  # (hd/2,)
+    inv = rope_freqs(hd, theta) if inv_freq is None else inv_freq  # (hd/2,)
     if mrope_sections:
         assert positions.ndim == 3 and positions.shape[-1] == len(mrope_sections)
         # section id per frequency slot
@@ -113,11 +115,14 @@ def kv_replication_factor(num_heads: int, num_kv_heads: int, axis_size: int) -> 
 # ---------------------------------------------------------------------------
 
 
-def _causal_chunk_attn(q_chunk, k, v, q_start, kv_len, window: int, shd=None):
-    """q_chunk: (B, C, H, G, hd) grouped query; k/v: (B, S, H, hd).
+def _causal_chunk_attn(q_chunk, k, v, q_start, kv_len, window: int, shd=None,
+                      scale=None):
+    """q_chunk: (B, C, H, G, hd) grouped query; k/v: (B, S, H, hd) (v may
+    be narrower than q and k).
 
     Masked softmax over keys [0, S) with causal (+ optional sliding window)
-    mask relative to absolute query positions q_start..q_start+C.
+    mask relative to absolute query positions q_start..q_start+C; scores
+    are scaled by ``scale`` (default hd^-0.5).
     """
     B, C, H, G, hd = q_chunk.shape
     S = k.shape[1]
@@ -126,8 +131,8 @@ def _causal_chunk_attn(q_chunk, k, v, q_start, kv_len, window: int, shd=None):
     # bf16 operands with fp32 accumulate (native MXU path; avoids
     # materializing an fp32 copy of K)
     scores = jnp.einsum(
-        "bchgd,bshd->bhgcs", q_chunk, k, preferred_element_type=jnp.float32
-    ) / math.sqrt(hd)
+        "bchgd,bshd->bhgcs", q_chunk, k, preferred_element_type=jnp.float32)
+    scores = scores / math.sqrt(hd) if scale is None else scores * scale
     qpos = q_start + jnp.arange(C)[:, None]  # (C, 1)
     kpos = jnp.arange(S)[None, :]  # (1, S)
     mask = kpos <= qpos
@@ -145,10 +150,12 @@ def _causal_chunk_attn(q_chunk, k, v, q_start, kv_len, window: int, shd=None):
     return out
 
 
-def causal_attention(q, k, v, *, chunk: int, window: int = 0, shd=None):
+def causal_attention(q, k, v, *, chunk: int, window: int = 0, shd=None,
+                     scale=None):
     """Reference causal attention with GQA, scanned over query chunks.
 
-    q: (B, S, Hq, hd); k, v: (B, S, KVH, hd).  Returns (B, S, Hq, hd).
+    q: (B, S, Hq, hd); k, v: (B, S, KVH, hd) (v's last dim may differ).
+    Returns (B, S, Hq, v's last dim); ``scale`` as in ``_causal_chunk_attn``.
     Non-divisible S is zero-padded on the query side (outputs sliced off).
 
     When KVH does not divide the model axis, K/V are expanded to MHA so the
@@ -179,12 +186,13 @@ def causal_attention(q, k, v, *, chunk: int, window: int = 0, shd=None):
 
     def body(_, qc_i):
         qc, i = qc_i
-        out = _causal_chunk_attn(qc, k, v, i * chunk, None, window, shd=shd)
+        out = _causal_chunk_attn(qc, k, v, i * chunk, None, window, shd=shd,
+                                 scale=scale)
         return (), out
 
     qs = qg.reshape(B, n, chunk, KVH, G, hd).transpose(1, 0, 2, 3, 4, 5)
     _, outs = jax.lax.scan(body, (), (qs, jnp.arange(n)))
-    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sp, Hq, hd)
+    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sp, Hq, v.shape[-1])
     return out[:, :S]
 
 

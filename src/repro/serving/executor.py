@@ -34,6 +34,8 @@ from repro.core.blocks import (
     chain_params,
     chain_prefill_fused,
     chain_signature,
+    is_mla,
+    is_moe,
 )
 from repro.observability import MetricsRegistry, Tracer
 from repro.serving.kv_pool import KVManager
@@ -78,6 +80,21 @@ class DecodeState:
         default_factory=list)
     buffered_counts: List[int] = field(default_factory=list)  # per lane
     probs: Optional[jnp.ndarray] = None  # (B, V) probs of latest next_token
+    # (3,) int32 on device for a chain with expert layers: token-expert
+    # pairs, experts hit and expert-layer calls of its megasteps so far
+    moe: Optional[jnp.ndarray] = None
+
+
+def _has_moe(steps) -> bool:
+    return any(is_moe(b.params) for b, _ in steps)
+
+
+def _no_mla_moe(steps, path: str) -> None:
+    """The per-hop and speculative paths run the dense llama layer only."""
+    for b, _ in steps:
+        if is_mla(b.params) or is_moe(b.params):
+            raise NotImplementedError(
+                f"the {path} path does not run MLA or MoE blocks ({b.id})")
 
 
 class BlockExecutor:
@@ -95,6 +112,11 @@ class BlockExecutor:
         self._c_host_syncs = self.metrics.counter("host_syncs")
         self._c_spec_attempts = self.metrics.counter("spec_attempts")
         self._c_spec_hits = self.metrics.counter("spec_hits")
+        # expert routing of the fused megasteps, counted on the device and
+        # added here when a group syncs
+        self._c_moe = [self.metrics.counter(n) for n in (
+            "moe_decode_pairs", "moe_decode_experts_hit",
+            "moe_decode_layer_calls")]
         # per-block batch occupancy: every batched device call observes its
         # batch width (compare p50/mean against EngineConfig.max_block_batch)
         self._h_group_batch = self.metrics.histogram("group_batch")
@@ -128,6 +150,7 @@ class BlockExecutor:
             return fn
         impl = self.attn_impl
         hop = [(block, adapters)]
+        _no_mla_moe(hop, "per-hop decode")
         if block.has_kv:
             if block.cfg.sliding_window:
                 raise NotImplementedError(
@@ -161,11 +184,12 @@ class BlockExecutor:
         fn = self._prefill_fns.get(key)
         if fn is None:
             hop = [(block, adapters)]
+            impl = self.attn_impl
 
             @jax.jit
             def block_prefill(params, x):
                 (b, ads), = bind_params(hop, params)
-                return block_prefill_raw(b, x, adapters=ads)
+                return block_prefill_raw(b, x, adapters=ads, impl=impl)
 
             fn = functools.partial(block_prefill, chain_params(hop))
             self._prefill_fns[key] = fn
@@ -215,11 +239,12 @@ class BlockExecutor:
     def chain_prefill_fn(self, steps, sig):
         fn = self._chain_prefill_fns.get(sig)
         if fn is None:
+            impl = self.attn_impl
 
             @jax.jit
             def chain_prefill(params, tok, lens):
                 return chain_prefill_fused(bind_params(steps, params), tok,
-                                           lens)
+                                           lens, impl)
 
             fn = functools.partial(chain_prefill, chain_params(steps))
             self._chain_prefill_fns[sig] = fn
@@ -240,11 +265,9 @@ class BlockExecutor:
                 if not block.has_kv:
                     continue
                 _, pool = kv.pool_for(block)
-                k_r, v = kvs[hop]
                 for bi, s in enumerate(states):
-                    pool.write_prefill(s.rid, i,
-                                       k_r[bi:bi + 1, :s.prompt_len],
-                                       v[bi:bi + 1, :s.prompt_len])
+                    pool.write_prefill(s.rid, i, *(
+                        t[bi:bi + 1, :s.prompt_len] for t in kvs[hop]))
                 hop += 1
         with Tracer.span("exec.sync"):
             nxt_h, probs_h = jax.device_get((nxt, probs))
@@ -276,7 +299,9 @@ class BlockExecutor:
 
     def fused_fn(self, steps, sig):
         """One jitted megastep per chain signature; returns (fn, pool_keys)
-        where ``pool_keys`` orders the KV-pool signatures the chain needs."""
+        where ``pool_keys`` orders the KV-pool signatures the chain needs.
+        A chain with expert layers takes and returns one more argument,
+        its ``DecodeState.moe`` counts."""
         cached = self._fused_fns.get(sig)
         if cached is not None:
             return cached
@@ -284,10 +309,11 @@ class BlockExecutor:
         pool_keys, pool_index = self._pool_layout(steps)
 
         @functools.partial(jax.jit, donate_argnums=(2, 3))
-        def chain_decode(params, tok, pools_k, pools_v, tables, kv_len):
+        def chain_decode(params, tok, pools_k, pools_v, tables, kv_len,
+                         *moe):
             return chain_decode_fused(bind_params(steps, params), pool_index,
                                       tok, pools_k, pools_v, tables, kv_len,
-                                      attn_impl=impl)
+                                      *moe, attn_impl=impl)
 
         out = (functools.partial(chain_decode, chain_params(steps)),
                tuple(pool_keys))
@@ -304,6 +330,7 @@ class BlockExecutor:
         cached = self._spec_fns.get(key)
         if cached is not None:
             return cached
+        _no_mla_moe(steps, "speculative")
         impl = self.attn_impl
         pool_keys, pool_index = self._pool_layout(steps)
         sur_keys, _ = self._pool_layout(sur_steps)
@@ -355,9 +382,13 @@ class BlockExecutor:
         if not ds.emitted:
             return  # never stepped: host state is still authoritative
         with Tracer.span("exec.sync"):
-            blocks, nxt, probs = jax.device_get(
-                (tuple(t for t, _ in ds.emitted), ds.next_token, ds.probs))
+            blocks, nxt, probs, moe = jax.device_get(
+                (tuple(t for t, _ in ds.emitted), ds.next_token, ds.probs,
+                 ds.moe))
             self._c_host_syncs.inc()
+            if moe is not None:
+                for c, v in zip(self._c_moe, moe):
+                    c.inc(int(v))
             for i, s in enumerate(ds.states):
                 for t, cnt in zip(blocks, (c for _, c in ds.emitted)):
                     s.tokens.extend(int(tok) for tok in t[i, :cnt[i]])
@@ -381,7 +412,8 @@ class BlockExecutor:
             kv_len=jnp.asarray([s.kv_len for s in states], jnp.int32),
             tables=tuple(tables),
             kv_len0=[s.kv_len for s in states],
-            buffered_counts=[0] * len(states))
+            buffered_counts=[0] * len(states),
+            moe=jnp.zeros(3, jnp.int32) if _has_moe(steps) else None)
         self.decode_states[rids] = ds
         for r in rids:
             self._rid_group[r] = rids
@@ -402,8 +434,11 @@ class BlockExecutor:
         pv = tuple(p.v_pages for p in pools)
         self._c_group_calls.inc()
         self._h_group_batch.observe(len(states))
-        nxt, probs, pk, pv, kv_len = fn(ds.next_token, pk, pv, ds.tables,
-                                        ds.kv_len)
+        moe = () if ds.moe is None else (ds.moe,)
+        nxt, probs, pk, pv, kv_len, *moe = fn(ds.next_token, pk, pv,
+                                              ds.tables, ds.kv_len, *moe)
+        if moe:
+            ds.moe, = moe
         for p, k_new, v_new in zip(pools, pk, pv):
             p.k_pages, p.v_pages = k_new, v_new
         B = len(states)

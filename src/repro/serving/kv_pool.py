@@ -2,7 +2,10 @@
 preemption (DESIGN.md §2).
 
 One ``KVPool`` per (kv_heads, head_dim, dtype) signature holds two
-head-major page slabs ``(num_pages, KVH, page_size, hd)`` for K and V.  Every
+head-major page slabs ``(num_pages, KVH, page_size, hd)`` for K and V; a
+multi-head latent attention pool (signature ``("latent", width)``) holds
+one token-minor slab ``(num_pages, 1, width, page_size)`` of latent rows
+(``kernels/mla_paged_attention`` says why) and no V.  Every
 attention-bearing chain step of every in-flight request owns a run of
 page ids (a *slot*) carved out of the same slab, so requests from
 different apps — and the shared foundation blocks they batch on — draw
@@ -21,7 +24,9 @@ harmlessly.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -30,15 +35,17 @@ import numpy as np
 TRASH_PAGE = 0  # reserved scratch page for padded table entries
 
 
-@jax.jit
-def kv_write_prefill(pages, new, idx):
+@functools.partial(jax.jit, static_argnames=("token_minor",))
+def kv_write_prefill(pages, new, idx, token_minor: bool = False):
     """Scatter a prefill's raw K or V ``(1, S, KVH, hd)`` into the pages
-    ``idx`` of a head-major slab ``(num_pages, KVH, page_size, hd)``,
+    ``idx`` of a head-major slab ``(num_pages, KVH, page_size, hd)`` (with
+    ``token_minor``, of a ``(num_pages, KVH, hd, page_size)`` slab),
     padding S up to ``len(idx)`` whole pages.  The slab is not donated, so
     XLA copies it whole around the scatter."""
-    npages, page = idx.shape[0], pages.shape[2]
+    npages, page = idx.shape[0], pages.shape[3 if token_minor else 2]
     new = jnp.pad(new[0], ((0, npages * page - new.shape[1]), (0, 0), (0, 0)))
-    new = new.reshape(npages, page, *new.shape[1:]).transpose(0, 2, 1, 3)
+    new = new.reshape(npages, page, *new.shape[1:]).transpose(
+        (0, 2, 3, 1) if token_minor else (0, 2, 1, 3))
     return pages.at[idx].set(new.astype(pages.dtype))
 
 
@@ -54,15 +61,18 @@ class KVPool:
 
     def __init__(self, num_pages: int, page_size: int, kv_heads: int,
                  head_dim: int, dtype=jnp.bfloat16, metrics=None,
-                 name: str = ""):
+                 name: str = "", values: bool = True):
         assert num_pages >= 2, "pool needs at least the trash page + one slot"
         self.page_size = page_size
         self.num_pages = num_pages
         self.kv_heads = kv_heads
         self.head_dim = head_dim
-        shape = (num_pages, kv_heads, page_size, head_dim)
+        # a latent pool keeps one token-minor slab: its V is part of the
+        # K rows
+        shape = ((num_pages, kv_heads, page_size, head_dim) if values
+                 else (num_pages, kv_heads, head_dim, page_size))
         self.k_pages = jnp.zeros(shape, dtype)
-        self.v_pages = jnp.zeros(shape, dtype)
+        self.v_pages = jnp.zeros(shape, dtype) if values else None
         # page 0 reserved (TRASH_PAGE); never handed out
         self._free: List[int] = list(range(num_pages - 1, 0, -1))
         self.slots: Dict[Tuple[int, int], KVSlot] = {}  # (rid, step) -> slot
@@ -90,8 +100,9 @@ class KVPool:
     @property
     def page_bytes(self) -> int:
         """K+V bytes held by one page."""
-        return 2 * (self.page_size * self.kv_heads * self.head_dim
-                    * jnp.dtype(self.k_pages.dtype).itemsize)
+        slabs = 1 if self.v_pages is None else 2
+        return slabs * (self.page_size * self.kv_heads * self.head_dim
+                        * jnp.dtype(self.k_pages.dtype).itemsize)
 
     @property
     def used_pages(self) -> int:
@@ -143,8 +154,9 @@ class KVPool:
 
     # -- prefill scatter ----------------------------------------------------
 
-    def write_prefill(self, rid: int, step: int, k_r, v):
-        """Scatter a prefill's raw K/V (1, S, KVH, hd) into the slot's pages."""
+    def write_prefill(self, rid: int, step: int, k_r, v=None):
+        """Scatter a prefill's raw K/V (1, S, KVH, hd) into the slot's pages
+        (a latent pool's rows come as ``k_r`` alone)."""
         pages = self.slots[(rid, step)].pages
         idx = jnp.asarray(pages[:self.pages_needed(k_r.shape[1])], jnp.int32)
         # each call makes a whole new slab (not donated): waiting for this
@@ -152,9 +164,11 @@ class KVPool:
         # back-to-back calls queue copies until HBM is full; the other
         # slab's write keeps the device busy meanwhile
         self.k_pages.block_until_ready()
-        self.k_pages = kv_write_prefill(self.k_pages, k_r, idx)
-        self.v_pages.block_until_ready()
-        self.v_pages = kv_write_prefill(self.v_pages, v, idx)
+        self.k_pages = kv_write_prefill(self.k_pages, k_r, idx,
+                                        token_minor=self.v_pages is None)
+        if self.v_pages is not None:
+            self.v_pages.block_until_ready()
+            self.v_pages = kv_write_prefill(self.v_pages, v, idx)
 
 
 # ---------------------------------------------------------------------------
@@ -167,9 +181,10 @@ class KVSnapshot:
     """Host-side copy of a preempted request's pages (spill strategy).
 
     Keyed by (pool signature, chain step); each value is the (K, V) page
-    stack exactly as it sat in the device slabs."""
-    pages: Dict[Tuple[Tuple[int, int], int],
-                Tuple[np.ndarray, np.ndarray]] = field(default_factory=dict)
+    stack exactly as it sat in the device slabs (V None for a latent
+    pool)."""
+    pages: Dict[Tuple[Tuple, int], Tuple[np.ndarray, Optional[np.ndarray]]
+                ] = field(default_factory=dict)
     kv_bytes: int = 0
 
 
@@ -189,22 +204,25 @@ class KVManager:
         self.dtype = dtype
         self.metrics = metrics  # shared registry: per-pool page gauges
         self.tracer = tracer    # spill/restore lifecycle events (§8)
-        self.pools: Dict[Tuple[int, int], KVPool] = {}
+        self.pools: Dict[Tuple, KVPool] = {}
 
-    def pool_for(self, block) -> Tuple[Tuple[int, int], KVPool]:
+    def pool_for(self, block) -> Tuple[Tuple, KVPool]:
         """The (signature key, pool) a block's KV slots live in; pools are
         created lazily on first use of a signature."""
         key = block.kv_signature
         pool = self.pools.get(key)
         if pool is None:
-            pool = self.pools[key] = KVPool(self.num_pages, self.page_size,
-                                            key[0], key[1], dtype=self.dtype,
-                                            metrics=self.metrics)
+            latent = key[0] == "latent"
+            heads, width = (1, key[1]) if latent else key
+            pool = self.pools[key] = KVPool(
+                self.num_pages, self.page_size, heads, width,
+                dtype=self.dtype, metrics=self.metrics,
+                name=f"latent{width}" if latent else "", values=not latent)
         return key, pool
 
     # -- admission planning --------------------------------------------------
 
-    def plan(self, steps) -> Dict[Tuple[int, int], int]:
+    def plan(self, steps) -> Dict[Tuple, int]:
         """Slots needed per pool signature for one request's resolved chain
         steps (``[(block, adapters), ...]``)."""
         need: Dict[Tuple[int, int], int] = {}
@@ -244,8 +262,10 @@ class KVManager:
             for r, step in [k for k in pool.slots if k[0] == rid]:
                 slot = pool.slots[(r, step)]
                 idx = jnp.asarray(slot.pages, jnp.int32)
-                snap.pages[(key, step)] = (np.asarray(pool.k_pages[idx]),
-                                           np.asarray(pool.v_pages[idx]))
+                snap.pages[(key, step)] = (
+                    np.asarray(pool.k_pages[idx]),
+                    None if pool.v_pages is None
+                    else np.asarray(pool.v_pages[idx]))
                 snap.kv_bytes += len(slot.pages) * pool.page_bytes
                 pool.free(r, step)
         if self.tracer is not None:
@@ -264,8 +284,9 @@ class KVManager:
             idx = jnp.asarray(slot.pages, jnp.int32)
             pool.k_pages = pool.k_pages.at[idx].set(
                 jnp.asarray(k_np, pool.k_pages.dtype))
-            pool.v_pages = pool.v_pages.at[idx].set(
-                jnp.asarray(v_np, pool.v_pages.dtype))
+            if v_np is not None:
+                pool.v_pages = pool.v_pages.at[idx].set(
+                    jnp.asarray(v_np, pool.v_pages.dtype))
         if self.tracer is not None:
             self.tracer.event(rid, "restore", kv_bytes=snap.kv_bytes,
                               slots=len(snap.pages))

@@ -141,3 +141,48 @@ def test_write_token_lanes_match_scatter(dtype):
                                   np.asarray(want_k[1:], np.float32))
     np.testing.assert_array_equal(np.asarray(got_v[1:], np.float32),
                                   np.asarray(want_v[1:], np.float32))
+
+
+@pytest.mark.parametrize("B,H,L,V,page,n", [
+    (3, 4, 48, 32, 16, 4),      # the test model's latent (32 + rope 16)
+    (2, 16, 576, 512, 128, 3),  # DeepSeek-V2's widths, a smaller page
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_mla_paged_attention(B, H, L, V, page, n, dtype):
+    """The latent kernel in interpret mode against its oracle at ragged
+    lengths: one sequence ends inside its first page, one on a page edge,
+    and table columns past a sequence's pages point at the trash page."""
+    from repro.kernels.mla_paged_attention.kernel import mla_paged_attention
+    from repro.kernels.mla_paged_attention.ref import mla_paged_attention_ref
+
+    rng = np.random.RandomState(0)
+    ks = jax.random.split(jax.random.PRNGKey(2), 2)
+    q = jax.random.normal(ks[0], (B, H, L), dtype)
+    pages = jax.random.normal(ks[1], (B * n + 1, 1, L, page), dtype)
+    tables = (rng.permutation(B * n) + 1).reshape(B, n)
+    lens = np.array([3, page, n * page - 5][:B])
+    tables[0, 1:] = 0  # ragged rows pad with the trash page
+    tables, lens = jnp.asarray(tables, jnp.int32), jnp.asarray(lens, jnp.int32)
+    out = mla_paged_attention(q, pages, tables, lens, value_dim=V,
+                              sm_scale=0.1147, interpret=True)
+    ref = mla_paged_attention_ref(q, pages, tables, lens, value_dim=V,
+                                  sm_scale=0.1147)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **_tol(dtype))
+
+
+def test_latent_token_write_lands_in_its_column():
+    """Each lane's latent row goes to column ``pos % page`` of its page of
+    the token-minor slab, and nothing else moves."""
+    from repro.kernels.mla_paged_attention.ops import write_latent_token
+
+    P, L, page = 6, 8, 4
+    pages = jnp.zeros((P, 1, L, page), jnp.float32)
+    tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    pos = jnp.asarray([5, 2], jnp.int32)
+    new = jnp.arange(2 * L, dtype=jnp.float32).reshape(2, L) + 1
+    out = np.asarray(write_latent_token(pages, tables, pos, new))
+    want = np.zeros((P, 1, L, page), np.float32)
+    want[2, 0, :, 1] = np.asarray(new[0])
+    want[3, 0, :, 2] = np.asarray(new[1])
+    np.testing.assert_array_equal(out, want)
